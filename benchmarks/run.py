@@ -12,9 +12,10 @@ import argparse
 import json
 import sys
 import time
+import traceback
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     ap.add_argument("--quick", action="store_true")
@@ -59,6 +60,7 @@ def main() -> None:
     print("name,us_per_call,derived")
     all_rows = {}
     elapsed_s = {}
+    failed = []
     for name, fn in figs.items():
         if only and not any(name.startswith(o) for o in only):
             continue
@@ -66,9 +68,11 @@ def main() -> None:
         try:
             rows = fn()
             status = "ok"
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — report, finish the rest
+            traceback.print_exc()
             rows = [{"error": repr(e)}]
             status = "error"
+            failed.append(name)
         dt = (time.perf_counter() - t0) * 1e6
         elapsed_s[name] = round(dt / 1e6, 3)
         derived = _derived(name, rows) if status == "ok" else status
@@ -117,6 +121,11 @@ def main() -> None:
         }]
         with open(args.json_out, "w") as f:
             json.dump(all_rows, f, indent=1)
+    if failed:
+        print(f"error: benchmark rows failed: {', '.join(failed)}",
+              file=sys.stderr)
+        return 1
+    return 0
 
 
 def _derived(name: str, rows) -> str:
@@ -191,4 +200,4 @@ def _derived(name: str, rows) -> str:
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

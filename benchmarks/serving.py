@@ -10,40 +10,30 @@ old slot pool could at equal device memory (a slot pool pins
 ``context_limit + max_new`` rows per admitted request; pages are charged
 per token actually held).
 
-Runs ``repro.launch.serve`` in a subprocess per mode (the driver owns the
-fake-device XLA flags; the benchmark process keeps its single CPU device
-per the harness contract) and reads the ``--stats-json`` artifact.
+Drives ``repro.launch.serve.serve()`` in this process on a 1x1 mesh (a
+process that holds JAX cannot hand the device to a child) and reads the
+stats it returns.
 """
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-import tempfile
 from typing import Dict, List, Sequence
 
 __all__ = ["paged_kv", "serving_engine"]
 
 
 def _run_serve(tag: str, extra: Sequence[str], *, n_req: int) -> Dict:
-    with tempfile.TemporaryDirectory() as td:
-        stats = os.path.join(td, f"serve-{tag}.json")
-        cmd = [sys.executable, "-m", "repro.launch.serve",
-               "--arch", "gemma3-1b", "--reduced",
-               "--trace", "github", "--requests", str(n_req),
-               "--context-limit", "96", "--max-new", "8",
-               "--stats-json", stats, *extra]
-        env = dict(os.environ)
-        env.setdefault("PYTHONPATH", "src")
-        r = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                           timeout=1200)
-        if r.returncode != 0:
-            raise RuntimeError(f"serve driver failed ({tag}): "
-                               f"{r.stderr[-2000:]}")
-        with open(stats) as f:
-            return json.load(f)
+    from repro.launch.mesh import make_mesh
+    from repro.launch.serve import build_parser, serve
+    args = build_parser().parse_args([
+        "--arch", "gemma3-1b", "--reduced",
+        "--trace", "github", "--requests", str(n_req),
+        "--context-limit", "96", "--max-new", "8", *extra])
+    rc, out = serve(args, make_mesh((1, 1), ("data", "model")))
+    if rc != 0:
+        raise RuntimeError(f"serve run failed ({tag}): rc={rc} "
+                           f"{out and out['error']}")
+    return out
 
 
 def _run_mode(mode: str, *, quick: bool) -> Dict:

@@ -8,15 +8,14 @@ import os
 # that trip XLA's CPU-collective rendezvous watchdog under load.
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
 
-import jax
-
 from repro.configs import get_arch
+from repro.launch.mesh import make_mesh
 from repro.launch.train import TrainLoopConfig, train
 
 
 def main():
     cfg = get_arch("llama3.2-3b").reduced()
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     loop = TrainLoopConfig(steps=4, global_batch=6, context=256,
                            dataset="github", compute_dtype="float32")
     _, _, history = train(cfg, mesh, loop)

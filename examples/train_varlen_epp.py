@@ -10,9 +10,8 @@ import argparse
 import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
 
-import jax
-
 from repro.configs import get_arch
+from repro.launch.mesh import make_mesh
 from repro.launch.train import TrainLoopConfig, train
 
 
@@ -23,7 +22,7 @@ def main():
     # ~100M params: gemma3 family reduced to 8 layers x 512 width
     cfg = get_arch("gemma3-1b").reduced(n_layers=8, d_model=512, n_heads=8,
                                         head_dim=64, vocab=8192)
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     loop = TrainLoopConfig(steps=args.steps, global_batch=16, context=2048,
                            dataset="github", ckpt_dir="runs/quickckpt",
                            ckpt_every=50, compute_dtype="float32")

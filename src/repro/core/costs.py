@@ -41,6 +41,18 @@ __all__ = ["CostModel", "fit_coefficients", "analytic_coefficients"]
 
 BWD_MULT = 2.0  # backward flops / forward flops
 
+# CostModel.m_step's constants, fitted to the peak memory the TPU compiler
+# reports for the llama3.2-3b training step on a described v5e (2 layers,
+# 1x1 and 2x2 meshes, remat depth 0..L_s):
+# bytes per parameter held through a step — bf16 weights, f32 master,
+# Adam m and v, plus f32 gradients and the update's temporaries
+STEP_BYTES_PER_PARAM = 17.0
+# compiled residual bytes over the analytic count (layout padding, fusion
+# temporaries)
+STEP_RESIDUAL_SCALE = 1.2
+HEAD_RESIDUAL = 10        # bytes per d_model per token the CE head keeps
+ATTN_BLOCK_KV = 512       # kernels/ref.blocked_flash_attention's KV tile
+
 
 # ---------------------------------------------------------------------------
 # Analytic coefficient derivation.
@@ -129,6 +141,25 @@ def _act_bytes_per_token(m: ModelSpec) -> float:
     elif m.d_ff > 0:
         per_layer += e * (D + 3 * m.d_ff + D)
     return m.n_layers * per_layer
+
+
+def _layer_residual_bytes_per_token(m: ModelSpec) -> float:
+    """Bytes one layer's autodiff keeps per token of its chunk when the
+    layer is not recomputed, as the executor's models compute it: the
+    bf16 tensors of :func:`_act_bytes_per_token` plus the f32 copies the
+    layers make (both RMS norms and the residual stream around them, RoPE
+    on q and k, the SwiGLU gate / up / activation)."""
+    e, D = m.bytes_per_act, m.d_model
+    f32 = 4 * 6 * D                                   # norms + residual adds
+    if not m.attn_free:
+        f32 += 4 * (m.n_heads * m.head_dim + m.d_kv)  # RoPE upcasts
+    if m.n_experts > 0:
+        f32 += 4 * 3 * m.d_ff_expert * (m.top_k + m.n_shared_experts)
+    elif m.d_ff > 0:
+        f32 += 4 * 3 * m.d_ff
+    if m.ssm_state > 0:
+        f32 += 4 * 3 * m.inner
+    return _act_bytes_per_token(m) / m.n_layers + f32 + e * D
 
 
 def analytic_coefficients(m: ModelSpec, c: ClusterSpec,
@@ -427,10 +458,59 @@ class CostModel:
             per_stage += m.vocab * m.d_model
         if stage == cl.d_p and not m.tie_embeddings:
             per_stage += m.vocab * m.d_model
-        if stage == cl.d_p and m.tie_embeddings:
-            per_stage += m.vocab * m.d_model  # tied head still materialized on use
+        if stage == cl.d_p and m.tie_embeddings and cl.d_p > 1:
+            # the last stage materializes the tied head on use; at d_p == 1
+            # it IS the embedding's stage, already counted above
+            per_stage += m.vocab * m.d_model
         bytes_per_param = 2 + 4 + 4 + 4 + 4   # bf16 + master + m + v + fp32 grad
         return per_stage * bytes_per_param / cl.d_s
+
+    # ------------------------------------------------------------------
+    # What the compiled step holds (the executor, not Eq. 10's runtime).
+    # ------------------------------------------------------------------
+    def m_step(self, *, n_ticks: int, cap: int, ctx_cap: int, l_ckpt: int,
+               traced: bool = False, v_stages: int = 1) -> float:
+        """Peak bytes per device of one compiled training step, as the
+        executor runs it.
+
+        Eq. 10 models a 1F1B runtime that frees a chunk's activations once
+        its backward ran. The executor instead differentiates one
+        ``lax.scan`` over ``n_ticks`` ticks (every chunk of the bucket,
+        bucket padding included, plus bubbles): the residuals of every
+        tick stay live until the backward reaches it. Per tick and per
+        layer it keeps the context KV the layer attends over, and either
+        the layer's whole residual set or, for the ``l_ckpt`` recomputed
+        layers, only its input. A stage that recomputes every layer
+        recomputes the CE head too (``runtime/pipeline.py``). A traced
+        per-(stage, chunk) table (``traced``) selects remat per tick with
+        ``lax.cond``, whose residuals are those of both branches: it saves
+        nothing. On top come the weights, optimizer state and gradients,
+        and one tick's backward working set (about half of a layer's
+        residuals, and the f32 probabilities of one KV tile). With
+        ``v_stages`` virtual stages a tick runs ``1/v`` of the stage's
+        layers.
+        """
+        m, cl = self.model, self.cluster
+        e, D = m.bytes_per_act, m.d_model
+        l_stage = -(-m.n_layers // cl.d_p)
+        l_s = l_stage // max(1, v_stages)          # layers a tick runs
+        l_ck = max(0, min(l_ckpt, l_s))
+        toks = cap / self.sp_degree
+        kv_pos = 2.0 * e * m.d_kv * self.kv_replication / cl.d_s
+        r_full = (STEP_RESIDUAL_SCALE * _layer_residual_bytes_per_token(m)
+                  * toks + kv_pos * (ctx_cap + cap))
+        r_ck = e * D * toks + kv_pos * ctx_cap
+        if traced:
+            tick = l_s * (r_full + r_ck) + HEAD_RESIDUAL * D * toks
+        else:
+            tick = (l_s - l_ck) * r_full + l_ck * r_ck
+            tick += (e if l_ckpt >= l_stage else HEAD_RESIDUAL) * D * toks
+        n_embed = m.vocab * D * (1 if m.tie_embeddings else 2)
+        params = ((m.param_count() - n_embed) / cl.d_p + n_embed) / cl.d_s
+        # a device's query rows times heads is n_heads * toks under every
+        # SP policy (ulysses shards heads, the others shard tokens)
+        working = 0.5 * r_full + 4.0 * m.n_heads * ATTN_BLOCK_KV * toks
+        return params * STEP_BYTES_PER_PARAM + n_ticks * tick + working
 
     # ------------------------------------------------------------------
     # Token capacity (Alg. 1 input C): max tokens resident at once.

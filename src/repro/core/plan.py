@@ -258,9 +258,23 @@ class ModelSpec:
         return self.param_count() - self.n_layers * dead
 
 
+# Per-chip peaks keyed by JAX ``device_kind``. "TPU v5 lite" is the TPU
+# v5e; source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI = 4 links x 50 GB/s). The "cpu"
+# entry measures nothing: CPU runs plan with the v5e's numbers so that
+# small-scale runs exercise the same capacity arithmetic.
+_V5E = dict(flops_per_chip=197e12, hbm_bytes=16e9, hbm_bw=819e9,
+            ici_bw=50e9)
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": _V5E,
+    "cpu": _V5E,
+}
+
+
 @dataclass(frozen=True)
 class ClusterSpec:
-    """Target hardware. Defaults = TPU v5e per the assignment constants."""
+    """Target hardware. Defaults = TPU v5e per the assignment constants;
+    :meth:`for_device` takes the peaks of the device a run is on."""
 
     d_p: int = 16                # pipeline stages (mesh axis "data")
     d_s: int = 16                # SP/FSDP/EP degree (mesh axis "model")
@@ -287,6 +301,19 @@ class ClusterSpec:
 
     def with_(self, **kw: Any) -> "ClusterSpec":
         return dataclasses.replace(self, **kw)
+
+    @classmethod
+    def for_device(cls, platform: str, device_kind: str,
+                   **axes: int) -> "ClusterSpec":
+        """Peaks of ``DEVICE_PEAKS[device_kind]`` (``"cpu"`` for any CPU);
+        ``axes`` sets ``d_p``/``d_s``/``n_pods``. A device kind missing
+        from the table raises: a wrong capacity would mis-plan memory."""
+        key = "cpu" if platform == "cpu" else device_kind
+        if key not in DEVICE_PEAKS:
+            raise ValueError(
+                f"no peak numbers for {platform} device kind "
+                f"{device_kind!r}; add it to DEVICE_PEAKS with its source")
+        return cls(**DEVICE_PEAKS[key], **axes)
 
 
 @dataclass

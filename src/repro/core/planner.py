@@ -29,7 +29,7 @@ from .plan import ExecutionPlan
 from .schedule import build_schedule, choose_schedule
 from .sp import SPConfig, sp_candidates, sp_legal
 
-__all__ = ["plan_batch", "estimate_plan_time", "PlannerConfig"]
+__all__ = ["plan_batch", "estimate_plan_time", "PlannerConfig", "step_memory"]
 
 
 @dataclass
@@ -49,6 +49,10 @@ class PlannerConfig:
     uniform_split: bool = False       # ablate: evenly split (w/o wbc)
     disable_ckpt: bool = False        # ablate: no checkpointing
     full_ckpt: bool = False          # ablate: checkpoint everything
+    # least remat depth the compiled step must apply: raised by the
+    # trainer when the TPU compiler refuses a step the memory model let
+    # through (launch/train.py)
+    ckpt_floor: int = 0
     # schedule backend: None => pick per plan from the bubble model
     # (core/schedule.choose_schedule); a registry name pins it. v_stages=0
     # lets the picker choose the virtual-stage count (interleaved only),
@@ -69,18 +73,71 @@ def _round_up(v: int, q: int) -> int:
     return ((max(v, 1) + q - 1) // q) * q
 
 
+def _set_uniform_ckpt(cm: CostModel, pipelines, depth: int) -> None:
+    """Every (stage, chunk) of every pipeline recomputes ``depth`` layers."""
+    for p in pipelines:
+        n = len(p.chunks)
+        p.ckpt = [[depth] * n for _ in range(cm.cluster.d_p)]
+        p.ckpt_diag = [depth] * (n + cm.cluster.d_p - 1)
+        avg_fwd = sum(cm.t_tot(c) for c in p.chunks) / max(n, 1)
+        p.est_recompute = (avg_fwd / cm.model.n_layers) * sum(p.ckpt_diag)
+
+
 def _apply_ablations(cm: CostModel, cfg: PlannerConfig,
                      grouping: GroupingResult) -> GroupingResult:
     if cfg.disable_ckpt or cfg.full_ckpt:
         per_stage = max(1, cm.model.n_layers // cm.cluster.d_p)
-        val = 0 if cfg.disable_ckpt else per_stage
-        for p in grouping.pipelines:
-            n = len(p.chunks)
-            p.ckpt = [[val] * n for _ in range(cm.cluster.d_p)]
-            p.ckpt_diag = [val] * (n + cm.cluster.d_p - 1)
-            avg_fwd = sum(cm.t_tot(c) for c in p.chunks) / max(n, 1)
-            p.est_recompute = (avg_fwd / cm.model.n_layers) * sum(p.ckpt_diag)
+        _set_uniform_ckpt(cm, grouping.pipelines,
+                          0 if cfg.disable_ckpt else per_stage)
     return grouping
+
+
+def step_memory(cm: CostModel, plan: ExecutionPlan) -> float:
+    """:meth:`CostModel.m_step` of ``plan``'s compiled bucket: the ticks
+    of its schedule over the bucket's rounded chunk count, and the remat
+    the executor realizes (a static depth, or a traced table)."""
+    from .schedule import get_schedule
+    key = plan.bucket_key(cm.cluster.d_s)
+    _, table, _ = plan.ckpt_policy(key.n_chunks)
+    spec = get_schedule(plan.schedule, plan.v_stages)
+    return cm.m_step(n_ticks=spec.scan_ticks(key.n_chunks, cm.cluster.d_p),
+                     cap=key.cap, ctx_cap=key.ctx_cap, l_ckpt=key.l_ckpt,
+                     traced=table is not None, v_stages=plan.v_stages)
+
+
+def _fit_step_memory(cm: CostModel, plan: ExecutionPlan,
+                     cfg: PlannerConfig) -> None:
+    """Raise the plan's recomputation until its compiled step fits.
+
+    The ILP budgets Eq. 10's 1F1B windows; the executor keeps every
+    tick's residuals and realizes only a static depth (see
+    :meth:`CostModel.m_step`). When the solved remat does not fit that
+    way, or is below ``cfg.ckpt_floor``, every (stage, chunk) takes the
+    least uniform depth that fits — the whole stage when none does, and
+    the compiler has the last word. Records the estimate in
+    ``plan.meta["step_bytes"]``. The no-checkpointing ablation is left
+    as solved.
+    """
+    if not plan.pipelines or cfg.disable_ckpt:
+        return
+    capacity = cfg.capacity_bytes or cm.cluster.capacity_bytes
+    l_stage = -(-cm.model.n_layers // cm.cluster.d_p)
+    need = step_memory(cm, plan)
+    _, table, _ = plan.ckpt_policy()
+    depth = 0 if table is not None else plan.uniform_ckpt()
+    if need > capacity or depth < cfg.ckpt_floor:
+        for depth in range(max(depth, cfg.ckpt_floor), l_stage + 1):
+            _set_uniform_ckpt(cm, plan.pipelines, depth)
+            need = step_memory(cm, plan)
+            if need <= capacity:
+                break
+        from .schedule import PipelineSimulator
+        for p in plan.pipelines:
+            res = PipelineSimulator(cm, p.chunks, p.f2b, p.n_split,
+                                    p.ckpt).run()
+            p.est_time, p.est_peak_mem = res.makespan, res.per_stage_peak_mem
+        plan.est_total_time = sum(p.est_time for p in plan.pipelines)
+    plan.meta["step_bytes"] = need
 
 
 def _quick_estimate(cm: CostModel, chunking: ChunkingResult) -> float:
@@ -279,6 +336,7 @@ def plan_batch(cm: CostModel, lengths: Sequence[int],
               "sp_policy": cm_c.sp_policy,
               "sp_sweep": sp_sweep},
     )
+    _fit_step_memory(cm_c, plan, cfg)
     return plan
 
 

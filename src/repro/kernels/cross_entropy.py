@@ -14,8 +14,9 @@ are never materialized at all —
   d(hidden) (vocab-sequential accumulation) and `[BV, D]` for d(W)
   (token-sequential accumulation). Peak live memory is O(BT*BV + BT*D).
 
-Tiles: BT=256 tokens x BV=1024 vocab => 1 MiB f32 logits tile + a 256xD
-accumulator — VMEM-resident at D <= 8192.
+Tiles: BT=256 tokens x BV vocab rows, BV=1024 shrunk with D until an f32
+[BV, D] tile fits 2 MiB (BV=128 at D=3072), so every kernel stays inside
+the default 16 MiB of scoped VMEM.
 
 ops.py wires these into a custom_vjp; the pure-jnp oracle is
 ``ref.streaming_cross_entropy`` / ``ref.cross_entropy_reference``.
@@ -37,6 +38,32 @@ __all__ = ["cross_entropy_fwd_pallas", "cross_entropy_bwd_dh_pallas",
 DEFAULT_BT = 256
 DEFAULT_BV = 1024
 NEG_INF = -1e30
+# budget for one f32 [BV, D] vocab tile: the double-buffered tiles, the
+# logits and the dW accumulator must share the 16 MiB of scoped VMEM a
+# Mosaic kernel gets by default
+VOCAB_TILE_BYTES = 2 * 2 ** 20
+
+
+def _fit_block_v(block_v: int, V: int, D: int) -> int:
+    """``block_v`` shrunk (to a multiple of 128, at least 128) until one f32
+    [block_v, D] tile fits ``VOCAB_TILE_BYTES``; at most ``V``."""
+    rows = max(128, VOCAB_TILE_BYTES // (4 * D) // 128 * 128)
+    return min(block_v, rows, V)
+
+
+def _dot(a, b, dims):
+    """MXU dot with f32 accumulation, operands in their own dtype (bf16
+    products are exact in f32). The precision is pinned: Mosaic rejects a
+    bf16 operand at the f32 contract precision that a process-wide
+    ``jax_default_matmul_precision="highest"`` would request."""
+    return jax.lax.dot_general(a, b, (dims, ((), ())),
+                               precision=jax.lax.Precision.DEFAULT,
+                               preferred_element_type=jnp.float32)
+
+
+def _tile_logits(h_ref, w_ref):
+    """[BT, BV] f32 logits of one (token, vocab) tile."""
+    return _dot(h_ref[...], w_ref[...], ((1,), (1,)))
 
 
 def _fwd_kernel(h_ref, w_ref, tgt_ref, valid_ref,
@@ -51,10 +78,7 @@ def _fwd_kernel(h_ref, w_ref, tgt_ref, valid_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         t_ref[...] = jnp.zeros_like(t_ref)
 
-    h = h_ref[...].astype(jnp.float32)          # [BT, D]
-    w = w_ref[...].astype(jnp.float32)          # [BV, D]
-    logits = jax.lax.dot_general(h, w, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+    logits = _tile_logits(h_ref, w_ref)         # [BT, BV] f32
     vocab_ids = v_idx * bv + jax.lax.broadcasted_iota(
         jnp.int32, (1, bv), dimension=1)        # [1, BV]
     live = vocab_ids < vocab
@@ -79,14 +103,14 @@ def _fwd_kernel(h_ref, w_ref, tgt_ref, valid_ref,
 def cross_entropy_fwd_pallas(hidden, w_vocab, targets, valid, *,
                              block_t: int = DEFAULT_BT,
                              block_v: int = DEFAULT_BV,
-                             interpret: bool = True
+                             interpret: bool = False
                              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """hidden [T, D], w_vocab [V, D], targets [T] int32, valid [T] bool ->
     (lse [T], tgt_logit [T]) fp32. T and V are padded by the caller."""
     T, D = hidden.shape
     V = w_vocab.shape[0]
     bt = min(block_t, T)
-    bv = min(block_v, V)
+    bv = _fit_block_v(block_v, V, D)
     assert T % bt == 0
     padV = (-V) % bv
     if padV:
@@ -137,19 +161,15 @@ def _bwd_dh_kernel(h_ref, w_ref, tgt_ref, lse_ref, g_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    h = h_ref[...].astype(jnp.float32)
-    w = w_ref[...].astype(jnp.float32)
-    logits = jax.lax.dot_general(h, w, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+    w = w_ref[...]
+    logits = _tile_logits(h_ref, w_ref)
     vocab_ids = v_idx * bv + jax.lax.broadcasted_iota(
         jnp.int32, (1, bv), dimension=1)
     live = vocab_ids < vocab
     p = jnp.where(live, jnp.exp(logits - lse_ref[...]), 0.0)  # [BT, BV]
     hit = vocab_ids == tgt_ref[...]
     coef = (p - jnp.where(hit, 1.0, 0.0)) * g_ref[...]        # [BT, BV]
-    acc_ref[...] += jax.lax.dot_general(
-        coef, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += _dot(coef.astype(w.dtype), w, ((1,), (0,)))
 
     @pl.when(v_idx == n_v - 1)
     def _finish():
@@ -159,12 +179,12 @@ def _bwd_dh_kernel(h_ref, w_ref, tgt_ref, lse_ref, g_ref,
 def cross_entropy_bwd_dh_pallas(hidden, w_vocab, targets, lse, g_rows, *,
                                 block_t: int = DEFAULT_BT,
                                 block_v: int = DEFAULT_BV,
-                                interpret: bool = True) -> jnp.ndarray:
+                                interpret: bool = False) -> jnp.ndarray:
     """d(hidden): [T, D]. ``g_rows`` [T] = upstream grad * valid mask."""
     T, D = hidden.shape
     V = w_vocab.shape[0]
     bt = min(block_t, T)
-    bv = min(block_v, V)
+    bv = _fit_block_v(block_v, V, D)
     padV = (-V) % bv
     if padV:
         w_vocab = jnp.concatenate(
@@ -199,20 +219,16 @@ def _bwd_dw_kernel(h_ref, w_ref, tgt_ref, lse_ref, g_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    h = h_ref[...].astype(jnp.float32)          # [BT, D]
-    w = w_ref[...].astype(jnp.float32)          # [BV, D]
+    h = h_ref[...]                              # [BT, D]
     v_idx = pl.program_id(0)
-    logits = jax.lax.dot_general(h, w, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+    logits = _tile_logits(h_ref, w_ref)
     vocab_ids = v_idx * bv + jax.lax.broadcasted_iota(
         jnp.int32, (1, bv), dimension=1)
     live = vocab_ids < vocab
     p = jnp.where(live, jnp.exp(logits - lse_ref[...]), 0.0)
     hit = vocab_ids == tgt_ref[...]
     coef = (p - jnp.where(hit, 1.0, 0.0)) * g_ref[...]        # [BT, BV]
-    acc_ref[...] += jax.lax.dot_general(
-        coef, h, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                   # [BV, D]
+    acc_ref[...] += _dot(coef.astype(h.dtype), h, ((0,), (0,)))  # [BV, D]
 
     @pl.when(t_idx == n_t - 1)
     def _finish():
@@ -222,12 +238,12 @@ def _bwd_dw_kernel(h_ref, w_ref, tgt_ref, lse_ref, g_ref,
 def cross_entropy_bwd_dw_pallas(hidden, w_vocab, targets, lse, g_rows, *,
                                 block_t: int = DEFAULT_BT,
                                 block_v: int = DEFAULT_BV,
-                                interpret: bool = True) -> jnp.ndarray:
+                                interpret: bool = False) -> jnp.ndarray:
     """d(w_vocab): [V, D]."""
     T, D = hidden.shape
     V = w_vocab.shape[0]
     bt = min(block_t, T)
-    bv = min(block_v, V)
+    bv = _fit_block_v(block_v, V, D)
     padV = (-V) % bv
     w_pad = w_vocab
     if padV:

@@ -91,7 +91,7 @@ def flash_attention_pallas(q, k, v, seg_q, seg_kv, pos_q, pos_kv, *,
                            scale: Optional[float] = None,
                            block_q: int = DEFAULT_BQ,
                            block_kv: int = DEFAULT_BKV,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool = False) -> jnp.ndarray:
     """q: [T, Hq, Dh]; k: [S, Hkv, Dh]; v: [S, Hkv, Dv] -> [T, Hq, Dv].
 
     Preconditions (enforced by the ops.py wrapper): T % block_q == 0,

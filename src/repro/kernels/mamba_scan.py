@@ -46,27 +46,34 @@ def _kernel(delta_ref, xs_ref, b_ref, c_ref, a_ref, reset_ref, h0_ref,
         h_ref[...] = h0_ref[...].astype(jnp.float32)
 
     a_mat = a_ref[...].astype(jnp.float32)          # [BDI, DS]
-    delta = delta_ref[...].astype(jnp.float32)      # [BT, BDI]
-    xs = xs_ref[...].astype(jnp.float32)            # [BT, BDI]
-    bmat = b_ref[...].astype(jnp.float32)           # [BT, DS]
-    cmat = c_ref[...].astype(jnp.float32)           # [BT, DS]
-    reset = reset_ref[...]                          # [BT, 1] int32
+    f32 = jnp.float32
+    # rows are read from the refs in sublane-aligned groups (Mosaic cannot
+    # load one bf16 row at an unaligned dynamic offset); the group's rows
+    # then step the recurrence with static slices
+    grp = 16 if bt % 16 == 0 else bt
 
-    def step(t, h):
-        d_t = jax.lax.dynamic_slice_in_dim(delta, t, 1, 0)     # [1, BDI]
-        x_t = jax.lax.dynamic_slice_in_dim(xs, t, 1, 0)
-        b_t = jax.lax.dynamic_slice_in_dim(bmat, t, 1, 0)      # [1, DS]
-        c_t = jax.lax.dynamic_slice_in_dim(cmat, t, 1, 0)
-        r_t = jax.lax.dynamic_slice_in_dim(reset, t, 1, 0)     # [1, 1]
-        a_t = jnp.exp(d_t.T * a_mat)                           # [BDI, DS]
-        a_t = jnp.where(r_t[0, 0] > 0, 0.0, a_t)
-        bx_t = (d_t * x_t).T * b_t                             # [BDI, DS]
-        h = a_t * h + bx_t
-        y_t = jnp.sum(h * c_t, axis=1, keepdims=True).T        # [1, BDI]
-        y_ref[pl.dslice(t, 1), :] = y_t.astype(y_ref.dtype)
+    def group(gi, h):
+        rows = pl.ds(pl.multiple_of(gi * grp, grp), grp)
+        d_g = delta_ref[rows, :].astype(f32)                   # [G, BDI]
+        x_g = xs_ref[rows, :].astype(f32)
+        b_g = b_ref[rows, :].astype(f32)                       # [G, DS]
+        c_g = c_ref[rows, :].astype(f32)
+        # reset kills the carried state: keep = 0 at a sequence start,
+        # broadcast along lanes only (Mosaic broadcasts one axis at a time)
+        keep_g = jnp.broadcast_to(
+            jnp.where(reset_ref[rows, :] > 0, 0.0, 1.0), d_g.shape)
+        ys = []
+        for i in range(grp):
+            d_t, x_t = d_g[i:i + 1], x_g[i:i + 1]              # [1, BDI]
+            b_t, c_t = b_g[i:i + 1], c_g[i:i + 1]              # [1, DS]
+            a_t = jnp.exp(d_t.T * a_mat) * keep_g[i:i + 1].T   # [BDI, DS]
+            bx_t = (d_t * x_t).T * b_t                         # [BDI, DS]
+            h = a_t * h + bx_t
+            ys.append(jnp.sum(h * c_t, axis=1, keepdims=True).T)  # [1, BDI]
+        y_ref[rows, :] = jnp.concatenate(ys, axis=0).astype(y_ref.dtype)
         return h
 
-    h = jax.lax.fori_loop(0, bt, step, h_ref[...])
+    h = jax.lax.fori_loop(0, bt // grp, group, h_ref[...])
     h_ref[...] = h
 
     @pl.when(t_idx == n_t - 1)
@@ -77,7 +84,7 @@ def _kernel(delta_ref, xs_ref, b_ref, c_ref, a_ref, reset_ref, h0_ref,
 def mamba_scan_pallas(delta, xs, B, C, A, reset, h0, *,
                       block_t: int = DEFAULT_BT,
                       block_di: int = DEFAULT_BDI,
-                      interpret: bool = True
+                      interpret: bool = False
                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """See module docstring. T must divide block_t (caller pads); DI must
     divide block_di."""

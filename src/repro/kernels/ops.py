@@ -1,17 +1,14 @@
-"""Public jit'd wrappers around the Pallas kernels.
+"""Public wrappers around the Pallas kernels.
 
-Each op pads its inputs to kernel tile geometry, dispatches to the Pallas
-kernel (``interpret=True`` everywhere except a real TPU backend) or to the
-blocked-jnp fallback, and unpads. ``fused_cross_entropy`` installs a
-``custom_vjp`` wiring the streaming forward to the streaming d(hidden)/d(W)
-backward kernels, so the `[T, V]` logits never exist in any pass.
+Each op pads its inputs to kernel tile geometry, runs the Pallas kernel
+and unpads. ``fused_cross_entropy`` installs a ``custom_vjp`` wiring the
+streaming forward to the streaming d(hidden)/d(W) backward kernels, so the
+`[T, V]` logits never exist in any pass.
 
-``use_pallas`` resolution:
-  * explicit True/False wins;
-  * None  => Pallas-in-interpret when running tests on CPU is *wasteful*,
-    so the default is the blocked-jnp path off-TPU and the Mosaic kernel on
-    TPU. The kernels' correctness is pinned by tests/test_kernels.py which
-    forces interpret=True.
+The kernels compile for the TPU (Mosaic). ``interpret=True`` runs them in
+the Pallas interpreter instead — only when the caller asks, as the CPU
+tests do; nothing here looks at the backend. The pure-jnp oracles live in
+``ref``.
 """
 
 from __future__ import annotations
@@ -22,25 +19,13 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from . import ref
 from .cross_entropy import (cross_entropy_bwd_dh_pallas,
                             cross_entropy_bwd_dw_pallas,
                             cross_entropy_fwd_pallas)
 from .flash_attention import flash_attention_pallas
 from .mamba_scan import mamba_scan_pallas
 
-__all__ = ["flash_attention", "fused_cross_entropy", "mamba_scan",
-           "on_tpu"]
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _resolve(use_pallas: Optional[bool]) -> bool:
-    if use_pallas is None:
-        return on_tpu()
-    return use_pallas
+__all__ = ["flash_attention", "fused_cross_entropy", "mamba_scan"]
 
 
 def _pad_to(x: jnp.ndarray, mult: int, axis: int, fill=0):
@@ -60,12 +45,8 @@ def flash_attention(q, k, v, seg_q, seg_kv, pos_q, pos_kv, *,
                     causal: bool = True, window: int = 0,
                     scale: Optional[float] = None,
                     block_q: int = 512, block_kv: int = 512,
-                    use_pallas: Optional[bool] = None) -> jnp.ndarray:
+                    interpret: bool = False) -> jnp.ndarray:
     """Packed-varlen attention. Padding rows get seg=-2 (matches nothing)."""
-    if not _resolve(use_pallas):
-        return ref.blocked_flash_attention(
-            q, k, v, seg_q, seg_kv, pos_q, pos_kv,
-            causal=causal, window=window, scale=scale)
     T = q.shape[0]
     S = k.shape[0]
     bq = min(block_q, max(8, T))
@@ -80,7 +61,7 @@ def flash_attention(q, k, v, seg_q, seg_kv, pos_q, pos_kv, *,
     out = flash_attention_pallas(
         qp, kp, vp, seg_qp, seg_kvp, pos_qp, pos_kvp,
         causal=causal, window=int(window), scale=scale,
-        block_q=bq, block_kv=bkv, interpret=not on_tpu())
+        block_q=bq, block_kv=bkv, interpret=interpret)
     return out[:T]
 
 
@@ -88,35 +69,32 @@ def flash_attention(q, k, v, seg_q, seg_kv, pos_q, pos_kv, *,
 # Fused streaming cross entropy (custom_vjp).
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _fused_ce(hidden, w_vocab, targets, valid, block_t, block_v):
-    lse, tl = cross_entropy_fwd_pallas(
-        hidden, w_vocab, targets, valid,
-        block_t=block_t, block_v=block_v, interpret=not on_tpu())
-    vf = valid.astype(jnp.float32)
-    return ((lse - tl) * vf).sum(), vf.sum()
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _fused_ce(hidden, w_vocab, targets, valid, block_t, block_v, interpret):
+    return _fused_ce_fwd(hidden, w_vocab, targets, valid, block_t, block_v,
+                         interpret)[0]
 
 
-def _fused_ce_fwd(hidden, w_vocab, targets, valid, block_t, block_v):
+def _fused_ce_fwd(hidden, w_vocab, targets, valid, block_t, block_v,
+                  interpret):
     lse, tl = cross_entropy_fwd_pallas(
         hidden, w_vocab, targets, valid,
-        block_t=block_t, block_v=block_v, interpret=not on_tpu())
+        block_t=block_t, block_v=block_v, interpret=interpret)
     vf = valid.astype(jnp.float32)
     out = (((lse - tl) * vf).sum(), vf.sum())
     return out, (hidden, w_vocab, targets, valid, lse)
 
 
-def _fused_ce_bwd(block_t, block_v, res, g):
+def _fused_ce_bwd(block_t, block_v, interpret, res, g):
     hidden, w_vocab, targets, valid, lse = res
     g_loss, _ = g
     g_rows = jnp.where(valid, g_loss, 0.0).astype(jnp.float32)
-    interp = not on_tpu()
     dh = cross_entropy_bwd_dh_pallas(hidden, w_vocab, targets, lse, g_rows,
                                      block_t=block_t, block_v=block_v,
-                                     interpret=interp)
+                                     interpret=interpret)
     dw = cross_entropy_bwd_dw_pallas(hidden, w_vocab, targets, lse, g_rows,
                                      block_t=block_t, block_v=block_v,
-                                     interpret=interp)
+                                     interpret=interpret)
     return dh, dw, None, None
 
 
@@ -125,18 +103,15 @@ _fused_ce.defvjp(_fused_ce_fwd, _fused_ce_bwd)
 
 def fused_cross_entropy(hidden, w_vocab, targets, valid, *,
                         block_t: int = 256, block_v: int = 1024,
-                        use_pallas: Optional[bool] = None
+                        interpret: bool = False
                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(sum_loss, n_valid) with logits never materialized in any pass."""
-    if not _resolve(use_pallas):
-        return ref.streaming_cross_entropy(hidden, w_vocab,
-                                           jnp.maximum(targets, 0), valid)
     T = hidden.shape[0]
     bt = min(block_t, max(8, T))
     hp = _pad_to(hidden, bt, 0)
     tp = _pad_to(targets.astype(jnp.int32), bt, 0, fill=-1)
     vp = _pad_to(valid, bt, 0, fill=False)
-    return _fused_ce(hp, w_vocab, tp, vp, bt, block_v)
+    return _fused_ce(hp, w_vocab, tp, vp, bt, block_v, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -145,18 +120,9 @@ def fused_cross_entropy(hidden, w_vocab, targets, valid, *,
 
 def mamba_scan(delta, xs, B, C, A, reset, h0, *,
                block_t: int = 256, block_di: int = 512,
-               use_pallas: Optional[bool] = None
+               interpret: bool = False
                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """y [T, DI], h_last [DI, DS] — see mamba_scan.py for semantics."""
-    if not _resolve(use_pallas):
-        a = jnp.exp(delta.astype(jnp.float32)[:, :, None]
-                    * A.astype(jnp.float32)[None])
-        a = jnp.where(reset.reshape(-1, 1, 1) > 0, 0.0, a)
-        bx = (delta * xs).astype(jnp.float32)[:, :, None] * \
-            B.astype(jnp.float32)[:, None, :]
-        hs, h_last = ref.mamba_scan_reference(a, bx, h0.astype(jnp.float32))
-        y = jnp.einsum("tds,ts->td", hs, C.astype(jnp.float32))
-        return y.astype(delta.dtype), h_last
     T = delta.shape[0]
     bt = min(block_t, max(8, T))
     # Padding steps must be state-neutral: delta=0 => a = exp(0*A) = 1 and
@@ -169,5 +135,5 @@ def mamba_scan(delta, xs, B, C, A, reset, h0, *,
     rp = _pad_to(reset.astype(jnp.int32), bt, 0, fill=0)
     y, h_last = mamba_scan_pallas(dp, xp, Bp, Cp, A, rp, h0,
                                   block_t=bt, block_di=block_di,
-                                  interpret=not on_tpu())
+                                  interpret=interpret)
     return y[:T], h_last

@@ -7,9 +7,10 @@ variants exist:
 * :func:`flash_attention_reference` — naive O(T*S) score materialization;
   bitwise-simple, used as the oracle.
 * :func:`blocked_flash_attention` — online-softmax over KV tiles in plain
-  jnp (lax.scan). Same math, O(T * BLOCK) memory; this is what the dry-run
-  lowers when the Mosaic kernel cannot (CPU backend), so the compiled HLO's
-  memory profile is representative of the TPU kernel.
+  jnp (lax.scan) with a FlashAttention-2 ``custom_vjp``: O(T * BLOCK)
+  live scores in either pass and O((T + S) * H * D) residuals under
+  ``grad``.
+  This is the attention core of the training and serving paths.
 
 Packed-varlen mask rule (shared by every implementation):
   attend(qi, kj)  iff  seg_q[i] == seg_kv[j]  and  seg_q[i] >= 0
@@ -19,6 +20,7 @@ Packed-varlen mask rule (shared by every implementation):
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -74,13 +76,18 @@ def blocked_flash_attention(q, k, v, seg_q, seg_kv, pos_q, pos_kv, *,
                             causal: bool = True, window=0,
                             scale: Optional[float] = None,
                             block_kv: int = 512) -> jnp.ndarray:
-    """Online-softmax over KV tiles; memory O(T * block_kv)."""
+    """Online-softmax over KV tiles with a FlashAttention-2 backward.
+
+    The forward keeps O(T * block_kv) scores live; under ``grad`` only q,
+    k, v (at their own ``Hkv`` heads: GQA expands each tile inside the
+    scan), the output and the per-row log-sum-exp are saved, and the
+    backward re-scans the KV tiles recomputing each tile's probabilities —
+    residuals are O((T + S) * H * D), never O(T * S). Rows that attend to
+    no key (padding) return zeros."""
     T, Hq, Dh = q.shape
-    S = k.shape[0]
+    S, Hkv = k.shape[:2]
     Dv = v.shape[-1]
     scale = scale if scale is not None else Dh ** -0.5
-    k = _expand_kv(k, Hq)
-    v = _expand_kv(v, Hq)
     pad = (-S) % block_kv
     if pad:
         k = jnp.concatenate([k, jnp.zeros((pad, *k.shape[1:]), k.dtype)])
@@ -88,25 +95,38 @@ def blocked_flash_attention(q, k, v, seg_q, seg_kv, pos_q, pos_kv, *,
         seg_kv = jnp.concatenate([seg_kv, jnp.full((pad,), -2, seg_kv.dtype)])
         pos_kv = jnp.concatenate([pos_kv, jnp.zeros((pad,), pos_kv.dtype)])
     nb = k.shape[0] // block_kv
-    kb = k.reshape(nb, block_kv, Hq, Dh)
-    vb = v.reshape(nb, block_kv, Hq, Dv)
+    kb = k.reshape(nb, block_kv, Hkv, Dh)
+    vb = v.reshape(nb, block_kv, Hkv, Dv)
     sb = seg_kv.reshape(nb, block_kv)
     pb = pos_kv.reshape(nb, block_kv)
+    return _flash(q, kb, vb, seg_q, sb, pos_q, pb, jnp.asarray(window),
+                  causal, scale)
 
-    window = jnp.asarray(window)
+
+def _tile_scores(q, kk, seg_q, sseg, pos_q, ppos, window, causal, scale):
+    """Scaled scores [Hq, T, block] of one KV tile (at its query-head
+    expansion) and its mask [1, T, block]. QK runs in the input dtype with
+    f32 accumulation: bf16 products are exact in f32, and the matmul reads
+    half the HBM of upcast inputs."""
+    s = jnp.einsum("thd,shd->hts", q, kk,
+                   preferred_element_type=jnp.float32) * scale
+    return s, _mask(seg_q, sseg, pos_q, ppos, causal, window)[None]
+
+
+def _flash_fwd_scan(q, kb, vb, seg_q, sb, pos_q, pb, window, causal, scale):
+    """Returns (out [T, Hq, Dv] in q.dtype, lse [Hq, T] f32)."""
+    T, Hq, _ = q.shape
+    Dv = vb.shape[-1]
 
     def body(carry, blk):
         acc, m_run, l_run = carry
         kk, vv, sseg, ppos = blk
-        # QK in the input dtype with f32 accumulation: bf16 products are
-        # exact in f32, and the matmul reads half the HBM of upcast inputs
-        s = jnp.einsum("thd,shd->hts", q, kk,
-                       preferred_element_type=jnp.float32) * scale
-        msk = _mask(seg_q, sseg, pos_q, ppos, causal, window)
-        s = jnp.where(msk[None], s, NEG_INF)
-        m_new = jnp.maximum(m_run, s.max(axis=-1))
+        kk, vv = _expand_kv(kk, Hq), _expand_kv(vv, Hq)
+        s, msk = _tile_scores(q, kk, seg_q, sseg, pos_q, ppos, window,
+                              causal, scale)
+        m_new = jnp.maximum(m_run, jnp.where(msk, s, NEG_INF).max(axis=-1))
         alpha = jnp.exp(m_run - m_new)
-        p = jnp.exp(s - m_new[..., None])
+        p = jnp.where(msk, jnp.exp(s - m_new[..., None]), 0.0)
         l_new = l_run * alpha + p.sum(axis=-1)
         acc = acc * alpha[..., None] + jnp.einsum(
             "hts,shd->htd", p, vv.astype(jnp.float32))
@@ -119,7 +139,59 @@ def blocked_flash_attention(q, k, v, seg_q, seg_kv, pos_q, pos_kv, *,
                                           (kb, vb, sb, pb))
     out = acc / jnp.maximum(l_run[..., None], 1e-30)
     out = jnp.where(l_run[..., None] > 0, out, 0.0)
-    return jnp.transpose(out, (1, 0, 2)).astype(q.dtype)
+    lse = m_run + jnp.log(jnp.maximum(l_run, 1e-30))
+    return jnp.transpose(out, (1, 0, 2)).astype(q.dtype), lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _flash(q, kb, vb, seg_q, sb, pos_q, pb, window, causal, scale):
+    return _flash_fwd_scan(q, kb, vb, seg_q, sb, pos_q, pb, window,
+                           causal, scale)[0]
+
+
+def _flash_fwd(q, kb, vb, seg_q, sb, pos_q, pb, window, causal, scale):
+    out, lse = _flash_fwd_scan(q, kb, vb, seg_q, sb, pos_q, pb, window,
+                               causal, scale)
+    return out, (q, kb, vb, seg_q, sb, pos_q, pb, window, out, lse)
+
+
+def _flash_bwd(causal, scale, res, do):
+    q, kb, vb, seg_q, sb, pos_q, pb, window, out, lse = res
+    dt = q.dtype
+    Hq, Hkv = q.shape[1], kb.shape[2]
+    do = do.astype(dt)
+
+    def fold_heads(g):
+        """Transpose of the GQA expansion: sum each kv head's query group."""
+        return g.reshape(g.shape[0], Hkv, Hq // Hkv, g.shape[-1]).sum(2)
+    # D_i = rowsum(dO * O): the softmax-Jacobian term of every tile
+    di = jnp.einsum("thd,thd->ht", do, out.astype(dt),
+                    preferred_element_type=jnp.float32)
+
+    def body(dq, blk):
+        kk, vv, sseg, ppos = blk
+        kk, vv = _expand_kv(kk, Hq), _expand_kv(vv, Hq)
+        s, msk = _tile_scores(q, kk, seg_q, sseg, pos_q, ppos, window,
+                              causal, scale)
+        p = jnp.where(msk, jnp.exp(s - lse[..., None]), 0.0)
+        dv = jnp.einsum("hts,thd->shd", p.astype(dt), do,
+                        preferred_element_type=jnp.float32)
+        dp = jnp.einsum("thd,shd->hts", do, vv.astype(dt),
+                        preferred_element_type=jnp.float32)
+        ds = (p * (dp - di[..., None])).astype(dt)
+        dq = dq + jnp.einsum("hts,shd->thd", ds, kk.astype(dt),
+                             preferred_element_type=jnp.float32) * scale
+        dk = jnp.einsum("hts,thd->shd", ds, q,
+                        preferred_element_type=jnp.float32) * scale
+        return dq, (fold_heads(dk).astype(kb.dtype),
+                    fold_heads(dv).astype(vb.dtype))
+
+    dq0 = jnp.zeros(q.shape, jnp.float32)
+    dq, (dk, dv) = jax.lax.scan(body, dq0, (kb, vb, sb, pb))
+    return (dq.astype(dt), dk, dv, None, None, None, None, None)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -145,8 +217,8 @@ def streaming_cross_entropy(hidden, w_vocab, targets, valid, *,
     """Vocab-tiled online-logsumexp CE: never materializes [T, V].
 
     Matches cross_entropy_reference exactly (up to fp reassociation); jnp
-    analogue of the Pallas streaming kernel; differentiable (XLA re-derives
-    the tiled backward through the scan).
+    analogue of the Pallas streaming kernel; differentiable (the backward
+    re-derives each vocab tile's logits through the remat'd scan body).
     """
     T, D = hidden.shape
     V = w_vocab.shape[0]
@@ -177,8 +249,11 @@ def streaming_cross_entropy(hidden, w_vocab, targets, valid, *,
     m0 = jnp.full((T,), NEG_INF, jnp.float32)
     l0 = jnp.zeros((T,), jnp.float32)
     t0 = jnp.zeros((T,), jnp.float32)
+    # the remat'd body saves only the [T] carries per vocab tile; its
+    # backward recomputes the tile's logits instead of keeping [T, V]
     (m_run, l_run, t_run), _ = jax.lax.scan(
-        body, (m0, l0, t0), (wb, jnp.arange(nb)))
+        jax.checkpoint(body, prevent_cse=False), (m0, l0, t0),
+        (wb, jnp.arange(nb)))
     lse = m_run + jnp.log(jnp.maximum(l_run, 1e-30))
     loss = jnp.where(valid, lse - t_run, 0.0)
     return loss.sum(), valid.astype(jnp.float32).sum()
@@ -233,7 +308,9 @@ def streaming_ce_stats(hidden, w_shard, local_targets, *,
     m0 = jnp.full((T,), NEG_INF, jnp.float32)
     l0 = jnp.zeros((T,), jnp.float32)
     t0 = jnp.zeros((T,), jnp.float32)
-    (m, l, t), _ = jax.lax.scan(body, (m0, l0, t0), (wb, jnp.arange(nb)))
+    # remat'd body: [T] carries per vocab tile are the only residuals
+    (m, l, t), _ = jax.lax.scan(jax.checkpoint(body, prevent_cse=False),
+                                (m0, l0, t0), (wb, jnp.arange(nb)))
     return m, l, t
 
 

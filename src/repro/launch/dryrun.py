@@ -34,15 +34,6 @@ from pathlib import Path
 import numpy as np
 
 
-def _cost_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` returns a dict on recent jax and a
-    one-element list of dicts on 0.4.x — normalize to a flat dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
-
-
 def _cell_skip_reason(cfg, shape) -> str:
     if shape.needs_subquadratic and not cfg.supports_long_decode:
         return ("skipped: pure full-attention arch at 500K decode "
@@ -89,8 +80,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Path,
                                           decode_state_struct,
                                           decode_step_fn,
                                           make_decode_geometry)
-    from repro.runtime.sharding import (mesh_axis_names, shard_dim_tree,
-                                        shard_map_compat)
+    from repro.runtime.sharding import mesh_axis_names, shard_dim_tree
     from repro.runtime.pipeline import pipeline_loss_fn
 
     cfg = get_arch(arch)
@@ -183,7 +173,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Path,
                 if pod:
                     batch = jax.tree.map(lambda x: x[0], batch)
                 return fn(params, batch)
-            mapped = shard_map_compat(prefill, mesh=mesh,
+            mapped = jax.shard_map(prefill, mesh=mesh,
                                    in_specs=(pspecs, bspecs),
                                    out_specs=(P(None, model),
                                               _ctx_specs(cfg, geom,
@@ -217,7 +207,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Path,
                             data_axis=data, model_axis=model)
         sspecs = decode_state_specs(cfg, geom, pod=pod, data=data,
                                     model=model)
-        mapped = shard_map_compat(fn, mesh=mesh,
+        mapped = jax.shard_map(fn, mesh=mesh,
                                in_specs=(pspecs, sspecs),
                                out_specs=(P(), sspecs),
                                check_vma=False)
@@ -231,7 +221,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Path,
     t_compile = time.perf_counter()
 
     mem = compiled.memory_analysis()
-    cost = _cost_dict(compiled)
+    cost = dict(compiled.cost_analysis())
     hlo = compiled.as_text()
     rec.update({
         "status": "ok",
@@ -280,7 +270,7 @@ def _run_encdec_cell(rec, cfg, shape, mesh, per_pod_batch, t0,
                                                make_encdec_geometry,
                                                prepare_encdec_params)
     from repro.runtime.sharding import (batch_specs, mesh_axis_names,
-                                        shard_dim_tree, shard_map_compat,
+                                        shard_dim_tree,
                                         stage_param_specs)
     import time as _time
 
@@ -355,7 +345,7 @@ def _run_encdec_cell(rec, cfg, shape, mesh, per_pod_batch, t0,
                                            gnorm=jnp.float32(1.0))
             return new_p, new_o, loss / jnp.maximum(n, 1)
 
-        mapped = shard_map_compat(step, mesh=mesh,
+        mapped = jax.shard_map(step, mesh=mesh,
                                in_specs=(pspecs, ospecs, bspecs),
                                out_specs=(pspecs, ospecs, P()),
                                check_vma=False)
@@ -367,7 +357,7 @@ def _run_encdec_cell(rec, cfg, shape, mesh, per_pod_batch, t0,
             if pod:
                 batch = jax.tree.map(lambda x: x[0], batch)
             return fn(params, batch)
-        mapped = shard_map_compat(fwd, mesh=mesh, in_specs=(pspecs, bspecs),
+        mapped = jax.shard_map(fwd, mesh=mesh, in_specs=(pspecs, bspecs),
                                out_specs=(P(), P()), check_vma=False)
         lowered = jax.jit(mapped).lower(params_shape, bstruct)
 
@@ -377,7 +367,7 @@ def _run_encdec_cell(rec, cfg, shape, mesh, per_pod_batch, t0,
         lowered.compile)
     t_compile = _time.perf_counter()
     mem = compiled.memory_analysis()
-    cost = _cost_dict(compiled)
+    cost = dict(compiled.cost_analysis())
     hlo = compiled.as_text()
     import numpy as _np
     rec.update({

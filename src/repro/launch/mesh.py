@@ -1,4 +1,4 @@
-"""Production meshes.
+"""Meshes, backend facts and the persistent compile cache.
 
 ``make_production_mesh`` is a FUNCTION (not a module-level constant) so
 importing this module never touches jax device state; the dry-run sets its
@@ -14,65 +14,61 @@ from __future__ import annotations
 
 import math
 import os
-from typing import Optional, Tuple
+from pathlib import Path
+from typing import Optional, Sequence, Tuple
 
-# XLA flags that let the compiled hand-off actually overlap: async
-# collectives (on by default since XLA 2024; older jaxlibs spelled it
-# --xla_gpu_enable_async_collectives, since removed) run the stream
-# ppermute on its own stream, and the latency-hiding scheduler hoists its
-# start above independent compute (runtime/executor.py issues the
-# ppermute before the accumulator fold for exactly this reason). The
-# triton fusion/gemm flags ride along from the same production recipe.
-# Every flag here must parse on the pinned jaxlib — XLA aborts the
-# process on unknown XLA_FLAGS entries.
-LATENCY_HIDING_FLAGS = (
-    "--xla_gpu_enable_triton_softmax_fusion=true "
-    "--xla_gpu_triton_gemm_any=True "
-    "--xla_gpu_enable_latency_hiding_scheduler=true "
-    "--xla_gpu_enable_highest_priority_async_stream=true"
-)
-
-# set to any non-empty value to leave XLA_FLAGS alone
-OPT_OUT_ENV = "REPRO_NO_LATENCY_HIDING"
+# JAX's persistent compilation cache lives here unless the environment
+# names another directory. The path is part of every cache key, so it is
+# fixed: a per-run (temporary, pid- or time-stamped) directory never hits.
+CHECKOUT_ROOT = Path(__file__).resolve().parents[3]
+DEFAULT_COMPILE_CACHE = CHECKOUT_ROOT / ".jax_cache"
 
 
-def configure_latency_hiding(*, enable: Optional[bool] = None) -> bool:
-    """Prepend the latency-hiding XLA flags to ``XLA_FLAGS``.
+def compile_cache_dir() -> str:
+    """``$JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or str(DEFAULT_COMPILE_CACHE))
 
-    Must run before the first ``import jax`` (XLA reads the env var once
-    at backend init); launchers call it at the top of ``main()``. On by
-    default; opt out with ``enable=False`` or by setting the
-    ``REPRO_NO_LATENCY_HIDING`` env var. Idempotent — flags already
-    present are not duplicated. Returns True when the flags are (now) in
-    ``XLA_FLAGS``.
-    """
-    if enable is None:
-        enable = not os.environ.get(OPT_OUT_ENV)
-    if not enable:
-        return False
-    import sys
-    if "jax" in sys.modules:
-        import warnings
-        warnings.warn(
-            "configure_latency_hiding() called after jax was imported; "
-            "XLA may already have initialized its backend and will ignore "
-            "the new flags. Call it before the first jax import.",
-            stacklevel=2)
-    current = os.environ.get("XLA_FLAGS", "")
-    if LATENCY_HIDING_FLAGS in current:
-        return True
-    os.environ["XLA_FLAGS"] = (LATENCY_HIDING_FLAGS + " " + current).strip()
-    return True
+
+def enable_compile_cache() -> Optional[str]:
+    """Point JAX's persistent compilation cache at :func:`compile_cache_dir`
+    unless a directory is already configured (JAX itself reads
+    ``JAX_COMPILATION_CACHE_DIR``). Returns the directory in use.
+
+    The CPU backend is left alone unless the environment names a
+    directory: its programs compile in seconds, and reloading them logs
+    host-feature mismatch errors."""
+    import jax
+    if (not jax.config.jax_compilation_cache_dir
+            and jax.default_backend() != "cpu"):
+        jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
+    return jax.config.jax_compilation_cache_dir
 
 
 def latency_hiding_active() -> bool:
-    """True when the latency-hiding scheduler flag is in ``XLA_FLAGS``.
+    """True when the backend overlaps collectives with compute.
 
-    Used by the program auditor: blocking collectives are only a hazard
-    when the run claims to overlap them.
+    The TPU compiler schedules async collectives by default; the CPU
+    backend runs them blocking. Used by the program auditor: blocking
+    collectives are only a hazard when the run claims to overlap them.
     """
-    return ("--xla_gpu_enable_latency_hiding_scheduler"
-            in os.environ.get("XLA_FLAGS", ""))
+    import jax
+    return jax.default_backend() == "tpu"
+
+
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
+              devices: Optional[Sequence] = None):
+    """The one mesh constructor: ``Auto`` axes, so shard_map's manual
+    collectives and jit's sharding propagation see the classic semantics
+    (``jax.make_mesh`` defaults to ``Explicit`` axes). ``devices``
+    defaults to the first ``prod(shape)`` devices of the default backend."""
+    import jax
+    from jax.sharding import AxisType
+    if devices is None:
+        devices = jax.devices()[:math.prod(shape)]
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -88,10 +84,4 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"jax.device_count() == {have}; use launch.mesh.make_mesh() "
             f"with a shape matching your slice, or (CPU dry-runs) raise "
             f"--xla_force_host_platform_device_count.")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Arbitrary meshes for tests/elastic rescale."""
-    import jax
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)

@@ -25,7 +25,7 @@ import argparse
 import sys
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-1b")
     ap.add_argument("--reduced", action="store_true")
@@ -97,12 +97,36 @@ def main():
                     help="program auditor on the engine's cold compile: "
                          "'warn' logs findings, 'error' aborts before a "
                          "hazardous executable enters the cache")
-    args = ap.parse_args()
+    return ap
 
+
+def main():
+    args = build_parser().parse_args()
     import os
     os.environ.setdefault(
         "XLA_FLAGS", f"--xla_force_host_platform_device_count={args.devices}")
-    import jax
+    from repro.launch.mesh import make_mesh
+    from repro.telemetry import atomic_write_json
+
+    dp, ds = (int(x) for x in args.mesh.split("x"))
+    rc, out = serve(args, make_mesh((dp, ds), ("data", "model")))
+    # the stats artifact is written even on a failed run — CI diagnoses
+    # exactly the failing case from it. Atomic (tmp + os.replace): an
+    # external scraper can never read a torn file
+    if args.stats_json and out is not None:
+        atomic_write_json(args.stats_json, out)
+    if out is not None and out["error"]:
+        print(f"error: {out['error']}", file=sys.stderr)
+        return rc
+    if rc == 0:
+        print("serve OK")
+    return rc
+
+
+def serve(args: argparse.Namespace, mesh):
+    """Run the trace ``args`` describes (``build_parser()``'s fields) on
+    ``mesh`` in this process. Returns ``(rc, stats)``; ``stats`` is None
+    when the trace is refused before the engine starts."""
     import jax.numpy as jnp
 
     from repro.configs import get_arch
@@ -111,13 +135,12 @@ def main():
     from repro.runtime.compile_cache import CompileCache
     from repro.serve import (EngineConfig, Request, ServeEngine,
                              one_shot_generate)
-    from repro.telemetry import StepTimeline, atomic_write_json
+    from repro.telemetry import StepTimeline
 
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    dp, ds = (int(x) for x in args.mesh.split("x"))
-    mesh = jax.make_mesh((dp, ds), ("data", "model"))
+    ds = mesh.shape["model"]
 
     page_sz = args.page_sz
     pages_per_seq = -(-(args.context_limit + args.max_new) // page_sz)
@@ -143,7 +166,7 @@ def main():
               f"--page-sz or lower --context-limit — context is never "
               f"silently truncated",
               file=sys.stderr)
-        return 2
+        return 2, None
 
     store = gc_report = None
     if args.cache_dir:
@@ -241,16 +264,7 @@ def main():
         out["cache_store"] = rep
         out["cache_store_gc"] = gc_report
         print(f"[cache-store] {rep}")
-    # the stats artifact is written even on a failed run — CI diagnoses
-    # exactly the failing case from it. Atomic (tmp + os.replace): an
-    # external scraper can never read a torn file
-    if args.stats_json:
-        atomic_write_json(args.stats_json, out)
-    if error:
-        print(f"error: {error}", file=sys.stderr)
-        return rc
-    print("serve OK")
-    return 0
+    return rc, out
 
 
 if __name__ == "__main__":

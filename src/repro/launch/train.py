@@ -25,6 +25,10 @@ from typing import Optional
 import numpy as np
 
 
+class StepMemoryError(RuntimeError):
+    """A compiled train step does not fit the device's memory."""
+
+
 @dataclass
 class TrainLoopConfig:
     steps: int = 50
@@ -56,6 +60,9 @@ class TrainLoopConfig:
     # ILP's per-(stage, chunk) checkpoint vector into the executor
     # (encoder/decoder stages and hot/cold chunks remat differently);
     # "uniform" collapses it to one max depth (the pre-vector behavior).
+    # Either way the planner raises the depth until the compiled step
+    # fits the device (core/planner.py, CostModel.m_step), and a step the
+    # TPU compiler still refuses raises it further (StepMemoryError).
     # Parity is guaranteed either way — remat never changes the math
     # (tests/test_remat_parity.py). Tradeoff: the vector is part of the
     # compiled step's identity, so memory-pressured workloads whose solved
@@ -125,7 +132,7 @@ def train(cfg_arch, mesh, loop: TrainLoopConfig, *, log=print):
     from repro.data import materialize_plan, sample_corpus_batch
     from repro.ft import (StragglerInjector, StragglerMonitor,
                           replan_costmodel)
-    from repro.launch.mesh import latency_hiding_active
+    from repro.launch.mesh import enable_compile_cache, latency_hiding_active
     from repro.lint import make_cache_lint, run_plan_checks
     from repro.optim import init_opt_state
     from repro.runtime import (CacheStore, CompileCache, TrainStepBuilder,
@@ -135,13 +142,17 @@ def train(cfg_arch, mesh, loop: TrainLoopConfig, *, log=print):
     from repro.telemetry import (ReplanConfig, ReplanController,
                                  StepTimeline, read_jsonl)
 
+    enable_compile_cache()
     pod, data, model = mesh_axis_names(mesh)
     n_pods = mesh.shape[pod] if pod else 1
     d_p, d_s = mesh.shape[data], mesh.shape[model]
     dtype = jnp.bfloat16 if loop.compute_dtype == "bfloat16" else jnp.float32
 
-    base_cm = CostModel(cfg_arch.spec, ClusterSpec(d_p=d_p, d_s=d_s,
-                                                   n_pods=n_pods))
+    dev = mesh.devices.flat[0]
+    base_cm = CostModel(cfg_arch.spec,
+                        ClusterSpec.for_device(dev.platform, dev.device_kind,
+                                               d_p=d_p, d_s=d_s,
+                                               n_pods=n_pods))
     monitor = StragglerMonitor(d_p=d_p)
     mgr = CheckpointManager(loop.ckpt_dir) if loop.ckpt_dir else None
 
@@ -206,6 +217,10 @@ def train(cfg_arch, mesh, loop: TrainLoopConfig, *, log=print):
     pinned = {"schedule": loop.schedule, "v_stages": loop.v_stages}
     remat_mode = ("stage_aware" if loop.ckpt_policy == "stage-aware"
                   else "uniform")
+    # least remat depth: raised when the compiler refuses a step
+    ckpt_floor = {"depth": 0}
+    dev_limit = ((dev.memory_stats() or {}).get("bytes_limit")
+                 or base_cm.cluster.hbm_bytes)
 
     def solve(cm, lengths):
         plan = plan_batch(cm, lengths,
@@ -213,6 +228,7 @@ def train(cfg_arch, mesh, loop: TrainLoopConfig, *, log=print):
                                         schedule=pinned["schedule"],
                                         v_stages=pinned["v_stages"],
                                         remat_mode=remat_mode,
+                                        ckpt_floor=ckpt_floor["depth"],
                                         sp_policy=loop.sp_policy,
                                         sp_degree=loop.sp_degree))
         pinned["schedule"], pinned["v_stages"] = plan.schedule, plan.v_stages
@@ -245,6 +261,7 @@ def train(cfg_arch, mesh, loop: TrainLoopConfig, *, log=print):
                                         schedule=pinned["schedule"],
                                         v_stages=pinned["v_stages"],
                                         remat_mode=remat_mode,
+                                        ckpt_floor=ckpt_floor["depth"],
                                         sp_policy=key.sp_policy,
                                         sp_degree=key.d_s_eff,
                                         token_capacity=key.cap))
@@ -345,7 +362,29 @@ def train(cfg_arch, mesh, loop: TrainLoopConfig, *, log=print):
                 params_shape, opt_shape, None, bstruct)
             if lint_hook is not None:
                 lint_stash["stablehlo"] = lowered.as_text()
-            return lowered.compile()
+            want = plan.meta.get("step_bytes", 0.0)
+            try:
+                compiled = lowered.compile()
+            except Exception as e:  # noqa: BLE001 — XlaRuntimeError
+                if "RESOURCE_EXHAUSTED" not in str(e):
+                    raise
+                raise StepMemoryError(
+                    f"bucket {key}: the compiler refuses the step "
+                    f"(planner estimate {want / 1e9:.2f} GB): "
+                    f"{str(e).splitlines()[0]}") from e
+            ma = compiled.memory_analysis()
+            # the CPU backend reports no peak: bound it by the buffers
+            peak = ma.peak_memory_in_bytes or (
+                ma.temp_size_in_bytes + ma.argument_size_in_bytes
+                + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+            log(f"[memory] bucket {key}: planner estimate "
+                f"{want / 1e9:.2f} GB, compiled peak {peak / 1e9:.2f} GB "
+                f"of {dev_limit / 1e9:.2f} GB")
+            if peak > dev_limit:
+                raise StepMemoryError(
+                    f"bucket {key}: compiled peak {peak / 1e9:.2f} GB "
+                    f"exceeds the device's {dev_limit / 1e9:.2f} GB")
+            return compiled
         m0, w0 = step_cache.stats.misses, step_cache.stats.warm_hits
         compiled = step_cache.get(key, build)
         if step_cache.stats.misses > m0:
@@ -355,6 +394,22 @@ def train(cfg_arch, mesh, loop: TrainLoopConfig, *, log=print):
         elif step_cache.stats.warm_hits > w0:
             timeline.record("compile", bucket=str(key), event="warm")
         return builder, compiled
+
+    def fitted_step(step, plan, corpus):
+        """``get_step(plan)``; while the step does not fit the device,
+        raise the remat floor past the plan's depth and re-plan."""
+        while True:
+            try:
+                return plan, corpus, get_step(plan)
+            except StepMemoryError as e:
+                key = plan.bucket_key(d_s)
+                depth = key.l_ckpt if key.ckpt.startswith("u") else 0
+                if journal_in or depth >= -(-cfg_arch.spec.n_layers // d_p):
+                    raise
+                ckpt_floor["depth"] = depth + 1
+                log(f"[memory] {e}; re-planning with remat depth >= "
+                    f"{ckpt_floor['depth']}")
+                plan, corpus = plan_for(step)
 
     # --- bootstrap: plan step 0 to learn the first bucket ---
     plan, corpus = plan_for(0)
@@ -369,7 +424,7 @@ def train(cfg_arch, mesh, loop: TrainLoopConfig, *, log=print):
         f"l_max={_key0.l_ckpt}"
         + ("" if _key0.ckpt.startswith("u") else
            f" per_stage_max={plan.ckpt_per_stage_max()}"))
-    builder, step_fn = get_step(plan)
+    plan, corpus, (builder, step_fn) = fitted_step(0, plan, corpus)
     params, opt, _ = builder.init_all(jax.random.PRNGKey(loop.seed))
     def _restack(saved: np.ndarray, tmpl) -> Optional[np.ndarray]:
         """Elastic reshard: stage-stacked [d_p_old, L_s_old, ...] leaves
@@ -430,11 +485,11 @@ def train(cfg_arch, mesh, loop: TrainLoopConfig, *, log=print):
     history = []
     next_plan, next_corpus = plan, corpus
     for step in range(start_step, loop.steps):
-        plan, corpus = next_plan, next_corpus
+        plan, corpus, (builder, step_fn) = fitted_step(step, next_plan,
+                                                       next_corpus)
         if journal_out is not None:
             journal_out.write(json.dumps({"step": step,
                                           "plan": plan.dumps()}) + "\n")
-        builder, step_fn = get_step(plan)
         key = plan.bucket_key(d_s)
         bucket = bucket_of(plan)
         batch = mat(plan, corpus, key.cap, key.n_chunks)
@@ -466,7 +521,9 @@ def train(cfg_arch, mesh, loop: TrainLoopConfig, *, log=print):
                              tokens=float(metrics["tokens"]), loss=loss,
                              per_stage_s=per_stage, probed=probed)
         history.append({"step": step, "loss": loss, "time": dt_step,
+                        "wall": wall,
                         "tokens": float(metrics["tokens"]),
+                        "slots": key.n_chunks * key.cap,
                         "solve_time": plan.solve_time})
         log(f"step {step:5d} loss {loss:.4f} tokens "
             f"{int(metrics['tokens'])} wall {dt_step:.2f}s "
@@ -524,10 +581,12 @@ def main():
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--context", type=int, default=2048)
     ap.add_argument("--dataset", default="github")
-    ap.add_argument("--mesh", default="2x4",
-                    help="DPxSP for CPU runs, e.g. 2x4 (needs "
-                         "xla_force_host_platform_device_count)")
-    ap.add_argument("--devices", type=int, default=8)
+    ap.add_argument("--mesh", default=None,
+                    help="DPxSP, e.g. 2x4; default: 2 pipeline stages "
+                         "(1 on one device) over all devices")
+    ap.add_argument("--devices", type=int, default=0,
+                    help="virtual CPU devices (CPU backend only; default: "
+                         "the --mesh size)")
     ap.add_argument("--ckpt-dir")
     ap.add_argument("--cache-dir", default=None,
                     help="persistent compile-cache directory (warm-starts "
@@ -563,10 +622,6 @@ def main():
                          "the schedule backend (split for zero-bubble-h1), "
                          "'on'/'off' force it for any backend (parity is "
                          "guaranteed either way)")
-    ap.add_argument("--no-latency-hiding", action="store_true",
-                    help="do not prepend the async-collective / "
-                         "latency-hiding-scheduler XLA flags (also: set "
-                         "REPRO_NO_LATENCY_HIDING=1)")
     ap.add_argument("--lint", default="warn",
                     choices=["off", "warn", "error"],
                     help="program auditor on cold compiles: 'warn' logs "
@@ -627,25 +682,33 @@ def main():
     args = ap.parse_args()
 
     import os
-
-    from repro.launch.mesh import configure_latency_hiding
-    configure_latency_hiding(
-        enable=False if args.no_latency_hiding else None)
-    # append (not setdefault — the latency-hiding flags may already be in
-    # XLA_FLAGS) the CPU placeholder-device count unless the caller set one
+    mesh_shape = (tuple(int(x) for x in args.mesh.split("x"))
+                  if args.mesh else None)
+    n_virtual = args.devices or (mesh_shape[0] * mesh_shape[1]
+                                 if mesh_shape else 0)
+    # the forced host-device count sizes the CPU backend only; an
+    # accelerator backend meshes its real devices
     flags = os.environ.get("XLA_FLAGS", "")
-    if "--xla_force_host_platform_device_count" not in flags:
+    if n_virtual and "--xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={args.devices}"
+            flags + f" --xla_force_host_platform_device_count={n_virtual}"
         ).strip()
     import jax
 
     from repro.configs import get_arch
+    from repro.launch.mesh import make_mesh
+    if args.devices and jax.default_backend() != "cpu":
+        print(f"[mesh] --devices ignored on {jax.default_backend()}")
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    dp, ds = (int(x) for x in args.mesh.split("x"))
-    mesh = jax.make_mesh((dp, ds), ("data", "model"))
+    if mesh_shape is None:
+        n = jax.device_count()
+        dp = 2 if n > 1 and n % 2 == 0 else 1
+        mesh_shape = (dp, n // dp)
+    mesh = make_mesh(mesh_shape, ("data", "model"))
+    print(f"[mesh] {dict(mesh.shape)} on {jax.device_count()} "
+          f"{jax.devices()[0].device_kind} device(s)")
     loop = TrainLoopConfig(steps=args.steps, global_batch=args.batch,
                            context=args.context, dataset=args.dataset,
                            ckpt_dir=args.ckpt_dir, resume=args.resume,

@@ -219,16 +219,15 @@ def main(argv=None) -> int:
                 flags +
                 f" --xla_force_host_platform_device_count={args.devices}"
             ).strip()
-        import jax
-
         from repro.configs import arch_names, get_arch
         from repro.core import (ClusterSpec, CostModel, PlannerConfig,
                                 plan_batch)
+        from repro.launch.mesh import make_mesh
         from repro.lint.plan_checks import run_plan_checks
 
         names = arch_names() if args.all else args.arch.split(",")
         d_p, d_s = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d_p, d_s), ("data", "model"))
+        mesh = make_mesh((d_p, d_s), ("data", "model"))
         lengths = [int(x) for x in args.lengths.split(",")]
         key_kwargs = {"split_bwd": args.split_bwd, "dtype": args.dtype}
 

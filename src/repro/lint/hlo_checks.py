@@ -6,7 +6,7 @@ The compile-path hook runs these on every cold compile — a
 and the launch sites stash the pre-compile StableHLO from the
 ``Lowered`` stage, so no extra tracing happens.
 
-Text anatomy this relies on (jax 0.4.x / XLA):
+Text anatomy this relies on (jax 0.9 / XLA):
 
 * StableHLO marks donated arguments with ``jax.buffer_donor = true`` on
   ``@main``'s parameters (arguments that could also be established as
@@ -198,5 +198,4 @@ def _blocking_collective(ctx, report: LintReport) -> None:
                    f"{n} blocking {op} op(s) in the compiled program "
                    f"while the latency-hiding scheduler is enabled — the "
                    f"collective serializes against compute instead of "
-                   f"overlapping; check the async-collective XLA flags "
-                   f"reached this compile", where=op)
+                   f"overlapping", where=op)

@@ -42,7 +42,7 @@ class ProgramArtifacts:
     stablehlo: Optional[str] = None  # Lowered.as_text()
     hlo: Optional[str] = None        # Compiled.as_text()
     platform: str = "cpu"            # jax.default_backend() at the site
-    latency_hiding: bool = False     # launch/mesh.configure_latency_hiding
+    latency_hiding: bool = False     # launch/mesh.latency_hiding_active
     const_threshold: int = 1 << 16   # program-baked-constant elements
 
     def available(self) -> Dict[str, bool]:

@@ -301,8 +301,8 @@ def run_stage_program(program: StageProgram, init_streams, init_state,
       carry's (second) receive buffer, and ``fold(tc, streams, state,
       acc)`` then folds the *pre-permute* buffer into the accumulator —
       the permute-independent fold work (the vocab-parallel CE matmul is
-      the big one) overlaps the in-flight collective under XLA's async
-      collectives + latency-hiding scheduler (launch/mesh.py flags). Same
+      the big one) overlaps the in-flight collective under the TPU
+      compiler's async collectives (on by default). Same
       values, same per-value op order: losses stay bitwise-identical.
     * ``program.split_bwd`` — the zero-bubble B/W split: the engine runs
       ``spec.drain_ticks`` W-grad ticks (:func:`_run_drain_scan`) feeding

@@ -85,8 +85,8 @@ class PipelineGeometry:
     split_bwd: bool = False
     # double-buffered stage hand-off: the executor issues the stream
     # ppermute before the accumulator fold, so the fold (the vocab-parallel
-    # CE matmul) overlaps the in-flight collective under the latency-hiding
-    # XLA flags (launch/mesh.py). Bitwise-identical results.
+    # CE matmul) overlaps the in-flight collective under the TPU compiler's
+    # async collectives. Bitwise-identical results.
     overlap_handoff: bool = True
 
     def __post_init__(self) -> None:
@@ -289,6 +289,11 @@ def pipeline_loss_fn(cfg: ArchConfig, geom: PipelineGeometry,
         ctx0 = init_stage_ctx(cfg, geom)
         x0 = jnp.zeros((cap_loc, s.d_model), dt)
         split = geom.split_bwd and mode == "train"
+        # a stage that recomputes every layer recomputes its CE head too:
+        # the head's f32 norm residuals ([cap, D] per tick) outweigh a
+        # layer input
+        remat_head = (geom.ckpt_table is None
+                      and geom.l_ckpt >= geom.layers_per_stage)
 
         def fold_acc(tc, x_out, ctx, acc):  # noqa: ARG001 (ctx unused)
             """Fold one tick's output into the accumulator (CE / greedy
@@ -307,11 +312,21 @@ def pipeline_loss_fn(cfg: ArchConfig, geom: PipelineGeometry,
                 primary = jax.lax.axis_index(model_axis) % rep == 0
                 seg = jnp.where(primary, seg, -1)
             tgt = targets_a[tc.idxc]
-            h_last = rms_norm(x_out, fn_gamma, cfg.rms_eps)
             if mode == "train":
-                return executor.fold_streaming_ce(
-                    tc, h_last, head_w, tgt, seg, acc,
-                    model_axis=model_axis, vocab_true=s.vocab)
+                # a fusion boundary: the CE fold compiles to the same
+                # arithmetic whether it runs before or after the hand-off
+                # ppermute is issued (without it XLA fuses the two
+                # placements differently and the grads drift by an ULP)
+                x_out, acc = jax.lax.optimization_barrier((x_out, acc))
+
+                def head_ce(x_out, gamma, w, acc):
+                    return executor.fold_streaming_ce(
+                        tc, rms_norm(x_out, gamma, cfg.rms_eps), w, tgt,
+                        seg, acc, model_axis=model_axis, vocab_true=s.vocab)
+                if remat_head:
+                    head_ce = jax.checkpoint(head_ce, prevent_cse=False)
+                return head_ce(x_out, fn_gamma, head_w, acc)
+            h_last = rms_norm(x_out, fn_gamma, cfg.rms_eps)
             # prefill: greedy next-token ids per position (the KV fills
             # the context carry — it IS the prefill cache). h_last is
             # token-sharded here, unlike decode's replicated rows.

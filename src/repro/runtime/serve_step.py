@@ -36,8 +36,8 @@ from repro.models.ssm import dt_rank_of
 
 from . import executor, sp
 from .program import StageProgram
-from .sharding import (gather_layer_params, mesh_axis_names, shard_dim_tree,
-                       shard_map_compat)
+from .sharding import (gather_layer_params, mesh_axis_names,
+                       shard_dim_tree)
 from .train_step import param_pspecs, prepare_params
 
 __all__ = ["DecodeGeometry", "decode_step_fn", "decode_state_struct",
@@ -869,7 +869,7 @@ class EngineStepBuilder:
         fn = engine_step_fn(self.cfg, self.geom, shard_dims,
                             data_axis=self.data_axis,
                             model_axis=self.model_axis)
-        mapped = shard_map_compat(
+        mapped = jax.shard_map(
             fn, mesh=self.mesh,
             in_specs=(pspecs, poolspecs, bspecs),
             out_specs=(P(None, self.model_axis), poolspecs),
@@ -885,7 +885,7 @@ class EngineStepBuilder:
         poolspecs = engine_pool_specs(self.data_axis, self.model_axis)
         cspecs = {"src": P(None), "dst": P(None)}
         fn = engine_copy_fn(self.geom, model_axis=self.model_axis)
-        mapped = shard_map_compat(
+        mapped = jax.shard_map(
             fn, mesh=self.mesh, in_specs=(poolspecs, cspecs),
             out_specs=poolspecs, check_vma=False)
         pool_struct = engine_pool_struct(self.cfg, self.geom)

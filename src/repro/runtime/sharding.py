@@ -29,7 +29,7 @@ __all__ = ["EP_PATH_RE", "stack_stages", "stack_grouped_stages",
            "stage_active_mask", "interleaved_layer_order", "restack_elastic",
            "unstack_stages", "zero3_dim", "shard_dim_tree",
            "stage_param_specs", "head_param_specs", "batch_specs",
-           "tree_paths_map", "mesh_axis_names", "shard_map_compat",
+           "tree_paths_map", "mesh_axis_names",
            "gather_layer_params", "gather_stage_params", "gather_params"]
 
 # expert-parallel leaves: sharded on their expert dim, never ZeRO-gathered
@@ -44,19 +44,6 @@ def mesh_axis_names(mesh: Mesh) -> Tuple[Optional[str], str, str]:
     if len(names) == 2:
         return None, names[0], names[1]
     raise ValueError(f"expected 2 or 3 mesh axes, got {names}")
-
-
-def shard_map_compat(f, *, mesh: Mesh, in_specs, out_specs,
-                     check_vma: bool = False):
-    """``jax.shard_map`` across jax versions: new releases expose it at the
-    top level (``check_vma``); older ones only under ``jax.experimental``
-    (same knob named ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
 
 
 def tree_paths_map(fn, tree):

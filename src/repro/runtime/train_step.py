@@ -21,7 +21,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.models import DecoderLM
@@ -32,7 +32,7 @@ from repro.optim import (AdamWConfig, adamw_update, compressed_psum,
 from . import sp
 from .pipeline import PipelineGeometry, pipeline_loss_fn
 from .sharding import (batch_specs, mesh_axis_names, shard_dim_tree,
-                       shard_map_compat, stack_stages, stage_param_specs)
+                       stack_stages, stage_param_specs)
 
 __all__ = ["TrainStepBuilder", "prepare_params", "make_geometry",
            "batch_struct"]
@@ -256,7 +256,7 @@ class TrainStepBuilder:
 
         mspec = {"loss": P(), "tokens": P(), "grad_norm": P(), "lr": P()}
         fn = functools.partial(self._step_local, shard_dims, norm_factors)
-        mapped = shard_map_compat(
+        mapped = jax.shard_map(
             fn, mesh=self.mesh,
             in_specs=(pspecs, ospecs,
                       pspecs if self.compress_pod_grads else None,
@@ -272,7 +272,17 @@ class TrainStepBuilder:
         return jax.jit(mapped, donate_argnums=(0, 1, 2))
 
     def init_all(self, key):
-        params = self.init_params(key)
-        opt = init_opt_state(params)
+        """Params and optimizer state, every leaf created in its mesh
+        sharding: no device ever holds the whole model."""
+        pspecs, ospecs, _ = self.specs(self.abstract_params(key))
+        named = lambda t: jax.tree.map(  # noqa: E731
+            lambda s: NamedSharding(self.mesh, s), t,
+            is_leaf=lambda x: isinstance(x, P))
+
+        def init(k):
+            params = self.init_params(k)
+            return params, init_opt_state(params)
+        params, opt = jax.jit(init, out_shardings=(named(pspecs),
+                                                   named(ospecs)))(key)
         err = init_error_state(params) if self.compress_pod_grads else None
         return params, opt, err

@@ -665,7 +665,7 @@ def one_shot_generate(cfg_arch, mesh, params, prompts: Sequence[Sequence[int]],
     from repro.runtime import make_geometry
     from repro.runtime.pipeline import pipeline_loss_fn
     from repro.runtime.sharding import (batch_specs, mesh_axis_names,
-                                        shard_dim_tree, shard_map_compat)
+                                        shard_dim_tree)
     from repro.runtime.train_step import batch_struct, param_pspecs
 
     compute_dtype = compute_dtype or jnp.float32
@@ -690,7 +690,7 @@ def one_shot_generate(cfg_arch, mesh, params, prompts: Sequence[Sequence[int]],
     ctx_spec = LayerCtx(kspec, kspec, None, None)
     fn = pipeline_loss_fn(cfg_arch, geom, shard_dims, pod_axis=None,
                           data_axis=data, model_axis=model, mode="prefill")
-    mapped = jax.jit(shard_map_compat(
+    mapped = jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=(pspecs, bspecs),
         out_specs=(P(None, model), ctx_spec), check_vma=False))
 

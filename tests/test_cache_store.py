@@ -310,13 +310,12 @@ def test_global_stats_carry_store_report(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_train_warm_start_end_to_end(tmp_path):
-    import jax
-
     from repro.configs import get_arch
+    from repro.launch.mesh import make_mesh
     from repro.launch.train import TrainLoopConfig, train
 
     cfg = get_arch("gemma3-1b").reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     mk = lambda: TrainLoopConfig(steps=2, global_batch=2, context=128,
                                  cache_dir=str(tmp_path / "cc"),
                                  compute_dtype="float32")

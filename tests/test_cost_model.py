@@ -193,3 +193,34 @@ def test_param_count_families():
     # olmoe: ~6.9B total, ~1.3B active
     assert 5.5e9 < moe.param_count() < 8.5e9
     assert 0.9e9 < moe.active_param_count() < 2.2e9
+
+
+def test_step_memory_counts_every_tick(cost_model):
+    """m_step: residuals grow with the scan's tick count, shrink with each
+    statically recomputed layer, and a traced table saves nothing."""
+    geo = dict(cap=2048, ctx_cap=4096)
+    l_s = cost_model.model.n_layers // cost_model.cluster.d_p
+    by_depth = [cost_model.m_step(n_ticks=11, l_ckpt=l, **geo)
+                for l in range(l_s + 1)]
+    assert all(a > b for a, b in zip(by_depth, by_depth[1:]))
+    per_tick = (cost_model.m_step(n_ticks=22, l_ckpt=0, **geo)
+                - by_depth[0]) / 11
+    assert per_tick > 0
+    assert by_depth[0] + per_tick * 11 == pytest.approx(
+        cost_model.m_step(n_ticks=22, l_ckpt=0, **geo))
+    traced = cost_model.m_step(n_ticks=11, l_ckpt=l_s, traced=True, **geo)
+    assert traced >= by_depth[0]
+
+
+def test_step_memory_of_the_smoke_step():
+    """llama3.2-3b at full width, 2 layers, one v5e, 8 ticks of 9216
+    tokens: the compiler's peak is 14.24e9 B at depth 2 (compile for a
+    described v5e); depth 0 needs several times the chip."""
+    from repro.configs import get_arch
+    spec = get_arch("llama3.2-3b").spec
+    spec = type(spec)(**{**spec.__dict__, "n_layers": 2})
+    cm = CostModel(spec, ClusterSpec.for_device("tpu", "TPU v5 lite",
+                                                d_p=1, d_s=1))
+    geo = dict(n_ticks=8, cap=9216, ctx_cap=9216)
+    assert cm.m_step(l_ckpt=2, **geo) == pytest.approx(14.24e9, rel=0.05)
+    assert cm.m_step(l_ckpt=0, **geo) > 2 * cm.cluster.capacity_bytes

@@ -25,6 +25,7 @@ _COMMON = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from jax.sharding import PartitionSpec as P
     jax.config.update("jax_default_matmul_precision", "highest")
 
@@ -37,7 +38,7 @@ _COMMON = textwrap.dedent("""
                                         init_stage_ctx)
     from repro.runtime.sharding import (gather_layer_params,
                                         gather_stage_params,
-                                        shard_dim_tree, shard_map_compat,
+                                        shard_dim_tree,
                                         stage_param_specs, batch_specs)
     from repro.runtime.train_step import prepare_params
 
@@ -195,7 +196,7 @@ _COMMON = textwrap.dedent("""
         cfg = get_arch("llama3.2-3b").reduced(n_layers=4, d_model=64,
                                               n_heads=4, head_dim=16,
                                               vocab=256)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         n = n_chunks + pad_chunks
         rng = np.random.default_rng(0)
         tokens = rng.integers(0, 256, (n_chunks, cap)).astype(np.int32)
@@ -223,7 +224,7 @@ _COMMON = textwrap.dedent("""
         return cfg, mesh, geom, params, batch, pspecs, bspecs, shard_dims
 
     def mapped_loss(loss_fn, mesh, pspecs, bspecs):
-        return jax.jit(shard_map_compat(
+        return jax.jit(jax.shard_map(
             loss_fn, mesh=mesh, in_specs=(pspecs, bspecs),
             out_specs=(P(), P()), check_vma=False))
 """)
@@ -442,7 +443,7 @@ def test_encdec_matches_prerefactor_bitwise():
         cfg = get_arch("seamless-m4t-v2").reduced(n_layers=2, d_model=64,
                                                   n_heads=4, head_dim=16,
                                                   vocab=256)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         n, cap, cape = 3, 32, 32
         geom = make_encdec_geometry(cfg, mesh, n_chunks=n, cap=cap,
                                     cap_enc=cape, ctx_cap=2 * cap, l_ckpt=0,

@@ -73,7 +73,7 @@ def test_flash_attention_context_kv():
     seg_kv = jnp.concatenate([jnp.zeros(C, jnp.int32), seg_q])
     pos_kv = jnp.concatenate([jnp.arange(C), pos_q])
     out_p = flash_attention_pallas(q, k, v, seg_q, seg_kv, pos_q, pos_kv,
-                                   block_q=32, block_kv=32)
+                                   block_q=32, block_kv=32, interpret=True)
     out_r = ref.flash_attention_reference(q, k, v, seg_q, seg_kv,
                                           pos_q, pos_kv)
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r),
@@ -90,7 +90,8 @@ def test_flash_attention_sliding_window(window):
     v = jax.random.normal(ks[2], (T, H, Dh))
     seg, pos = _varlen_meta(ks[2], T, 2)
     out_p = flash_attention_pallas(q, k, v, seg, seg, pos, pos,
-                                   window=window, block_q=32, block_kv=32)
+                                   window=window, block_q=32, block_kv=32,
+                                   interpret=True)
     out_r = ref.flash_attention_reference(q, k, v, seg, seg, pos, pos,
                                           window=window)
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r),
@@ -110,6 +111,103 @@ def test_blocked_ref_matches_naive_ref():
     b = ref.flash_attention_reference(q, k, v, seg, seg, pos, pos)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=2e-5, atol=2e-5)
+
+
+def _autodiff_blocked_attention(q, k, v, seg_q, seg_kv, pos_q, pos_kv, *,
+                                causal=True, window=0, block_kv=512):
+    """The online-softmax scan with no custom_vjp: autodiff differentiates
+    through every tile (O(T * S) residuals). Reference for the FA-2
+    backward of ``ref.blocked_flash_attention``."""
+    T, Hq, Dh = q.shape
+    scale = Dh ** -0.5
+    k, v = ref._expand_kv(k, Hq), ref._expand_kv(v, Hq)
+    pad = (-k.shape[0]) % block_kv
+    k = jnp.concatenate([k, jnp.zeros((pad, *k.shape[1:]), k.dtype)])
+    v = jnp.concatenate([v, jnp.zeros((pad, *v.shape[1:]), v.dtype)])
+    seg_kv = jnp.concatenate([seg_kv, jnp.full((pad,), -2, seg_kv.dtype)])
+    pos_kv = jnp.concatenate([pos_kv, jnp.zeros((pad,), pos_kv.dtype)])
+    nb = k.shape[0] // block_kv
+
+    def body(carry, blk):
+        acc, m_run, l_run = carry
+        kk, vv, sseg, ppos = blk
+        s = jnp.einsum("thd,shd->hts", q, kk,
+                       preferred_element_type=jnp.float32) * scale
+        msk = ref._mask(seg_q, sseg, pos_q, ppos, causal, jnp.asarray(window))
+        s = jnp.where(msk[None], s, ref.NEG_INF)
+        m_new = jnp.maximum(m_run, s.max(axis=-1))
+        alpha = jnp.exp(m_run - m_new)
+        p = jnp.exp(s - m_new[..., None])
+        l_new = l_run * alpha + p.sum(axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "hts,shd->htd", p, vv.astype(jnp.float32))
+        return (acc, m_new, l_new), None
+
+    init = (jnp.zeros((Hq, T, v.shape[-1]), jnp.float32),
+            jnp.full((Hq, T), ref.NEG_INF, jnp.float32),
+            jnp.zeros((Hq, T), jnp.float32))
+    (acc, _, l_run), _ = jax.lax.scan(
+        body, init, (k.reshape(nb, block_kv, Hq, Dh),
+                     v.reshape(nb, block_kv, Hq, -1),
+                     seg_kv.reshape(nb, block_kv),
+                     pos_kv.reshape(nb, block_kv)))
+    out = acc / jnp.maximum(l_run[..., None], 1e-30)
+    return jnp.transpose(out, (1, 0, 2)).astype(q.dtype)
+
+
+@pytest.mark.parametrize("causal,window,Hq,Hkv,S", [
+    (True, 0, 4, 4, 96),      # causal MHA
+    (True, 12, 4, 4, 96),     # sliding window
+    (True, 0, 8, 2, 96),      # GQA
+    (False, 0, 4, 2, 80),     # bidirectional GQA, padded KV tail (80 % 32)
+    (True, 0, 4, 1, 112),     # MQA over a context prefix, padded KV tail
+])
+def test_blocked_attention_custom_vjp_matches_autodiff(causal, window, Hq,
+                                                       Hkv, S):
+    """The FA-2 backward (saves q, k, v, out, lse; re-scans KV tiles) gives
+    the grads autodiff takes through the plain online-softmax scan."""
+    T, Dh, C = 64, 16, S - 64
+    ks = jax.random.split(jax.random.PRNGKey(S + Hq + window), 5)
+    q = jax.random.normal(ks[0], (T, Hq, Dh))
+    k = jax.random.normal(ks[1], (S, Hkv, Dh))
+    v = jax.random.normal(ks[2], (S, Hkv, Dh))
+    seg_q, pos_q = _varlen_meta(ks[3], T, 3, ctx=C)
+    seg_kv = jnp.concatenate([jnp.zeros(C, jnp.int32), seg_q])
+    pos_kv = jnp.concatenate([jnp.arange(C, dtype=jnp.int32), pos_q])
+    ct = jax.random.normal(ks[4], (T, Hq, Dh))
+    kw = dict(causal=causal, window=window, block_kv=32)
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v, seg_q, seg_kv, pos_q, pos_kv,
+                                   **kw) * ct).sum()
+
+    lr, gr = jax.value_and_grad(loss(_autodiff_blocked_attention),
+                                argnums=(0, 1, 2))(q, k, v)
+    lc, gc = jax.value_and_grad(loss(ref.blocked_flash_attention),
+                                argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(lc), float(lr), **TOL[jnp.float32])
+    for a, b in zip(gc, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   **TOL[jnp.float32])
+
+
+def test_blocked_attention_padding_rows_are_zero():
+    """A query that attends to no key (padding, seg -1) outputs zeros and
+    passes no gradient, as in the naive oracle."""
+    T, H, Dh = 48, 2, 16
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    q, k, v = (jax.random.normal(kk, (T, H, Dh)) for kk in ks)
+    seg = jnp.where(jnp.arange(T) < 40, 0, -1).astype(jnp.int32)
+    pos = jnp.arange(T, dtype=jnp.int32)
+    out = ref.blocked_flash_attention(q, k, v, seg, seg, pos, pos,
+                                      block_kv=16)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref.flash_attention_reference(
+            q, k, v, seg, seg, pos, pos)), **TOL[jnp.float32])
+    assert not np.asarray(out[40:]).any()
+    gq = jax.grad(lambda q: ref.blocked_flash_attention(
+        q, k, v, seg, seg, pos, pos, block_kv=16).sum())(q)
+    assert not np.asarray(gq[40:]).any()
 
 
 # ---------------------------------------------------------------------------
@@ -163,7 +261,7 @@ def test_ce_backward_matches_autodiff():
 
 
 def test_fused_ce_custom_vjp_end_to_end():
-    """ops.fused_cross_entropy(use_pallas=True) gradient == naive autodiff."""
+    """ops.fused_cross_entropy gradient (interpret mode) == naive autodiff."""
     key = jax.random.PRNGKey(9)
     T, D, V = 40, 16, 130
     ks = jax.random.split(key, 3)
@@ -174,7 +272,7 @@ def test_fused_ce_custom_vjp_end_to_end():
 
     def mean_p(h, w):
         s, n = fused_cross_entropy(h, w, tgt, valid, block_t=16, block_v=64,
-                                   use_pallas=True)
+                                   interpret=True)
         return s / n
 
     def mean_r(h, w):
@@ -242,13 +340,13 @@ def test_mamba_scan_carry_state():
     h0 = jnp.zeros((DI, DS))
 
     y_full, h_full = mamba_scan(delta, xs, B, C, A, reset, h0,
-                                block_t=16, use_pallas=True)
+                                block_t=16, interpret=True)
     half = T // 2
     y1, h_mid = mamba_scan(delta[:half], xs[:half], B[:half], C[:half], A,
-                           reset[:half], h0, block_t=16, use_pallas=True)
+                           reset[:half], h0, block_t=16, interpret=True)
     y2, h_end = mamba_scan(delta[half:], xs[half:], B[half:], C[half:], A,
                            jnp.zeros((T - half,), jnp.int32), h_mid,
-                           block_t=16, use_pallas=True)
+                           block_t=16, interpret=True)
     np.testing.assert_allclose(np.asarray(jnp.concatenate([y1, y2])),
                                np.asarray(y_full), rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(h_end), np.asarray(h_full),

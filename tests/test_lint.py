@@ -35,6 +35,7 @@ import jax.numpy as jnp  # noqa: E402
 from repro.core import ClusterSpec, CostModel, PlannerConfig, plan_batch
 from repro.core.plan import ExecutionPlan
 from repro.core.schedule import stream_perm
+from repro.launch.mesh import make_mesh
 from repro.lint import (
     LintError,
     LintReport,
@@ -80,7 +81,7 @@ def test_golden_f64_fixture():
     def bad(x):
         return (x.astype(jnp.float64) * 2.0).sum()
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jx = jax.make_jaxpr(bad)(jnp.ones((8,), jnp.bfloat16))
     report = run_program_checks(ProgramArtifacts(jaxpr=jx))
     _only_pass(report, "program-f64")
@@ -375,7 +376,7 @@ def test_pin_err_state_donated():
     from repro.configs import get_arch
 
     cfg = get_arch("gemma3-1b").reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     geom = make_geometry(cfg, mesh, n_chunks=2, cap=16, ctx_cap=16,
                          l_ckpt=0, compute_dtype=jnp.bfloat16)
     builder = TrainStepBuilder(cfg, mesh, geom, compress_pod_grads=True)

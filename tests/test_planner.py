@@ -88,3 +88,34 @@ def test_straggler_replanning_rebalances():
     slow = plan_batch(cm.with_slowdowns([1.0, 1.0, 1.8, 1.0]), lengths,
                       PlannerConfig(fixed_k=3))
     assert slow.est_total_time > base.est_total_time
+
+
+def test_plan_fits_the_compiled_step():
+    """The planner raises remat until the executor's step fits: the ILP's
+    1F1B windows admit no recomputation for llama3.2-3b on one v5e, but
+    the compiled step keeps every tick's residuals, so every layer (and
+    the CE head) is recomputed — a uniform depth the digest records."""
+    import dataclasses
+
+    from repro.configs import get_arch
+    spec = dataclasses.replace(get_arch("llama3.2-3b").spec, n_layers=2)
+    cm = CostModel(spec, ClusterSpec.for_device("tpu", "TPU v5 lite",
+                                                d_p=1, d_s=1))
+    plan = plan_batch(cm, [9176], PlannerConfig(bucket_rounding=256,
+                                                remat_mode="stage_aware"))
+    key = plan.bucket_key(1)
+    assert key.ckpt == "u2" and key.l_ckpt == 2
+    assert plan.meta["step_bytes"] <= cm.cluster.capacity_bytes
+
+
+def test_ckpt_floor_forces_uniform_depth(cost_model, skewed_lengths):
+    """A remat floor (what the trainer sets when the compiler refuses a
+    step) lifts every (stage, chunk) to at least that depth."""
+    base = plan_batch(cost_model, skewed_lengths,
+                      PlannerConfig(fixed_k=4, remat_mode="stage_aware"))
+    assert min(v for row in base.ckpt_table() for v in row) < 2
+    plan = plan_batch(cost_model, skewed_lengths,
+                      PlannerConfig(fixed_k=4, ckpt_floor=2,
+                                    remat_mode="stage_aware"))
+    assert {v for row in plan.ckpt_table() for v in row} == {2}
+    assert plan.bucket_key(4).ckpt == "u2"

@@ -33,6 +33,7 @@ _COMMON = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from jax.sharding import PartitionSpec as P
     jax.config.update("jax_default_matmul_precision", "highest")
 
@@ -40,8 +41,7 @@ _COMMON = textwrap.dedent("""
     from repro.models import DecoderLM, EncDecLM
     from repro.runtime import TrainStepBuilder, make_geometry
     from repro.runtime.pipeline import pipeline_loss_fn
-    from repro.runtime.sharding import (batch_specs, shard_dim_tree,
-                                        shard_map_compat, stage_param_specs)
+    from repro.runtime.sharding import (batch_specs, shard_dim_tree, stage_param_specs)
     from repro.runtime.train_step import prepare_params
 
     SCHEDULES = [("gpipe-1f1b", 1), ("interleaved-1f1b", 2),
@@ -52,7 +52,7 @@ _COMMON = textwrap.dedent("""
         cfg = get_arch("llama3.2-3b").reduced(n_layers=4, d_model=64,
                                               n_heads=4, head_dim=16,
                                               vocab=256)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         n, cap = 4, 32
         rng = np.random.default_rng(0)
         batch = {
@@ -78,11 +78,11 @@ _COMMON = textwrap.dedent("""
             def ids_only(p, b):
                 ids, _ctx = loss(p, b)
                 return ids
-            fn = jax.jit(shard_map_compat(
+            fn = jax.jit(jax.shard_map(
                 ids_only, mesh=mesh, in_specs=(pspecs, bspecs),
                 out_specs=P(None, "model"), check_vma=False))
         else:
-            fn = jax.jit(shard_map_compat(
+            fn = jax.jit(jax.shard_map(
                 loss, mesh=mesh, in_specs=(pspecs, bspecs),
                 out_specs=(P(), P()), check_vma=False))
         return fn, params, batch
@@ -94,7 +94,7 @@ _COMMON = textwrap.dedent("""
         cfg = get_arch("seamless-m4t-v2").reduced(n_layers=2, d_model=64,
                                                   n_heads=4, head_dim=16,
                                                   vocab=256)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         n, cap = 3, 32
         geom = make_encdec_geometry(cfg, mesh, n_chunks=n, cap=cap,
                                     cap_enc=cap, ctx_cap=2 * cap,
@@ -127,7 +127,7 @@ _COMMON = textwrap.dedent("""
             else:
                 arr = rng.normal(0, 0.5, v.shape).astype(np.float32)
             batch[k] = jnp.asarray(arr)
-        fn = jax.jit(shard_map_compat(
+        fn = jax.jit(jax.shard_map(
             encdec_pipeline_loss_fn(cfg, geom, sd, pod_axis=None),
             mesh=mesh, in_specs=(pspecs, bspecs), out_specs=(P(), P()),
             check_vma=False))

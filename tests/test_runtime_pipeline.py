@@ -18,6 +18,7 @@ _COMMON = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from jax.sharding import PartitionSpec as P
     jax.config.update("jax_default_matmul_precision", "highest")
 
@@ -27,7 +28,7 @@ _COMMON = textwrap.dedent("""
     from repro.models import DecoderLM, LayerCtx
     from repro.runtime import TrainStepBuilder, make_geometry
     from repro.runtime.pipeline import pipeline_loss_fn
-    from repro.runtime.sharding import shard_dim_tree, mesh_axis_names, shard_map_compat
+    from repro.runtime.sharding import shard_dim_tree, mesh_axis_names
     from repro.runtime.train_step import prepare_params, param_pspecs, batch_specs, batch_struct
 
     def reference_loss(cfg, raw_params, chunks, corpus, cap, ctx_cap):
@@ -51,7 +52,7 @@ _COMMON = textwrap.dedent("""
     def run_case(arch, seed=0, n_seq=6, ctx_limit=192, fixed_k=2):
         cfg = get_arch(arch).reduced(n_layers=4, d_model=64, n_heads=4,
                                      head_dim=16, vocab=256)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cm = CostModel(cfg.spec, ClusterSpec(d_p=2, d_s=4))
         rng = np.random.default_rng(seed)
         lens = [ctx_limit] + [int(x) for x in rng.integers(24, ctx_limit // 2, n_seq - 1)]
@@ -102,7 +103,7 @@ _COMMON = textwrap.dedent("""
         shard_dims = shard_dim_tree(params["stages"], 4)
 
         loss_fn = pipeline_loss_fn(cfg, geom, shard_dims, pod_axis=None)
-        mapped = jax.jit(shard_map_compat(
+        mapped = jax.jit(jax.shard_map(
             loss_fn, mesh=mesh, in_specs=(pspecs, bspecs),
             out_specs=(P(), P()), check_vma=False))
         loss_d, n_d = mapped(params, batch)
@@ -146,7 +147,7 @@ def test_pipeline_with_remat_matches():
         cfg = get_arch("llama3.2-3b").reduced(n_layers=4, d_model=64,
                                               n_heads=4, head_dim=16,
                                               vocab=256)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rng = np.random.default_rng(3)
         lens = [160, 40, 30]
         corpus = {i: rng.integers(0, 256, l).astype(np.int32)
@@ -172,7 +173,7 @@ def test_pipeline_with_remat_matches():
             builder = TrainStepBuilder(cfg, mesh, geom, param_dtype=jnp.float32)
             pspecs, _, bspecs = builder.specs(jax.eval_shape(lambda: params))
             loss_fn = pipeline_loss_fn(cfg, geom, shard_dims, pod_axis=None)
-            mapped = jax.jit(shard_map_compat(
+            mapped = jax.jit(jax.shard_map(
                 loss_fn, mesh=mesh, in_specs=(pspecs, bspecs),
                 out_specs=(P(), P()), check_vma=False))
             # also check gradients flow under remat

@@ -49,6 +49,7 @@ _COMMON = textwrap.dedent("""
     import jax, numpy as np
     jax.config.update("jax_default_matmul_precision", "highest")
     import jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
 
     from repro.configs import get_arch
     from repro.serve import (EngineConfig, Request, ServeEngine,
@@ -106,7 +107,7 @@ def _run(case: str):
 def test_engine_matches_one_shot_and_bucket_closure():
     _run("""
         cfg = llama()
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         econf = EngineConfig(n_items=4, cap_t=16, n_pages=24, page_sz=8,
                              pages_per_seq=6, k=1)
         reqs = trace(20, max_new=5)
@@ -139,7 +140,7 @@ def test_engine_matches_one_shot_and_bucket_closure():
 def test_speculative_k2_matches_k1():
     _run("""
         cfg = llama()
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         reqs = lambda: trace(8, seed=11, max_new=6)
         e1, g1 = run_engine(
             cfg, mesh, EngineConfig(n_items=4, cap_t=16, n_pages=24,
@@ -165,7 +166,7 @@ def test_speculative_k2_matches_k1():
 def test_chunked_prefill_matches_whole_prompt():
     _run("""
         cfg = gemma()
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         reqs = lambda: trace(6, seed=5, lo=10, hi=30, max_new=4)
         # cap_t=8 slices every prompt into multiple pipelined chunks;
         # cap_t=32 prefills each prompt whole
@@ -191,7 +192,7 @@ def test_chunked_prefill_matches_whole_prompt():
 def test_prefix_cache_parity_and_savings():
     _run("""
         cfg = llama()
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         # shared 16-token system prompt (= 2 full pages) + unique tails,
         # staggered so request 0's pages are published before the rest admit
         rng = np.random.default_rng(17)
@@ -519,7 +520,7 @@ def test_propose_draft_ngram_lookup():
 def test_preemption_preserves_outputs():
     _run("""
         cfg = llama()
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         reqs = lambda: trace(5, seed=9, lo=12, hi=21, max_new=8, spread=1.0)
         # 6 pages for 5 staggered requests wanting up to 4 each: arrivals
         # 2+ hit an occupied pool (pages are charged on write, so the
@@ -544,7 +545,7 @@ def test_preemption_preserves_outputs():
 def test_rejections_and_tpot_reporting():
     _run("""
         cfg = llama()
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         econf = EngineConfig(n_items=4, cap_t=16, n_pages=16, page_sz=8,
                              pages_per_seq=4, k=1)
         eng = ServeEngine(cfg, mesh, econf, param_dtype=jnp.float32, seed=3)

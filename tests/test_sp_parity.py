@@ -31,6 +31,7 @@ _COMMON = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from jax.sharding import PartitionSpec as P
     jax.config.update("jax_default_matmul_precision", "highest")
 
@@ -38,7 +39,7 @@ _COMMON = textwrap.dedent("""
     from repro.models import DecoderLM
     from repro.runtime import TrainStepBuilder, make_geometry
     from repro.runtime.pipeline import pipeline_loss_fn
-    from repro.runtime.sharding import shard_dim_tree, shard_map_compat
+    from repro.runtime.sharding import shard_dim_tree
     from repro.runtime.train_step import prepare_params
 
     def sp_case(sp_policy=None, sp_degree=0, schedule="gpipe-1f1b",
@@ -47,7 +48,7 @@ _COMMON = textwrap.dedent("""
         cfg = get_arch("llama3.2-3b").reduced(n_layers=4, d_model=64,
                                               n_heads=8, head_dim=16,
                                               vocab=256)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         n, cap = 4, 32
         rng = np.random.default_rng(0)
         seg = np.repeat(np.arange(n, dtype=np.int32)[:, None], cap, 1)
@@ -72,7 +73,7 @@ _COMMON = textwrap.dedent("""
         pspecs, _, bspecs = builder.specs(jax.eval_shape(lambda: params))
         sd = shard_dim_tree(params["stages"], 4)
         loss = pipeline_loss_fn(cfg, geom, sd, pod_axis=None, mode=mode)
-        fn = jax.jit(shard_map_compat(
+        fn = jax.jit(jax.shard_map(
             loss, mesh=mesh, in_specs=(pspecs, bspecs),
             out_specs=(P(), P()), check_vma=False))
         return fn, params, batch

@@ -37,6 +37,7 @@ _COMMON = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from jax.sharding import PartitionSpec as P
     jax.config.update("jax_default_matmul_precision", "highest")
 
@@ -44,7 +45,7 @@ _COMMON = textwrap.dedent("""
     from repro.models import DecoderLM
     from repro.runtime import TrainStepBuilder, make_geometry
     from repro.runtime.pipeline import pipeline_loss_fn
-    from repro.runtime.sharding import shard_dim_tree, shard_map_compat
+    from repro.runtime.sharding import shard_dim_tree
     from repro.runtime.train_step import prepare_params
 
     SCHEDULES = [("gpipe-1f1b", 1), ("interleaved-1f1b", 2),
@@ -55,7 +56,7 @@ _COMMON = textwrap.dedent("""
         cfg = get_arch("llama3.2-3b").reduced(n_layers=4, d_model=64,
                                               n_heads=4, head_dim=16,
                                               vocab=256)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         n, cap = 4, 32
         rng = np.random.default_rng(0)
         batch = {
@@ -78,7 +79,7 @@ _COMMON = textwrap.dedent("""
         pspecs, _, bspecs = builder.specs(jax.eval_shape(lambda: params))
         sd = shard_dim_tree(params["stages"], 4)
         loss = pipeline_loss_fn(cfg, geom, sd, pod_axis=None)
-        fn = jax.jit(shard_map_compat(
+        fn = jax.jit(jax.shard_map(
             loss, mesh=mesh, in_specs=(pspecs, bspecs),
             out_specs=(P(), P()), check_vma=False))
         return fn, params, batch
@@ -196,25 +197,18 @@ def test_overlap_handoff_bitwise():
 # ---------------------------------------------------------------------------
 
 def test_configure_latency_hiding_env_handling(monkeypatch):
-    from repro.launch.mesh import (LATENCY_HIDING_FLAGS, OPT_OUT_ENV,
-                                   configure_latency_hiding)
-    monkeypatch.delenv(OPT_OUT_ENV, raising=False)
-    monkeypatch.setenv("XLA_FLAGS", "--prior=1")
-    assert configure_latency_hiding()
-    flags = os.environ["XLA_FLAGS"]
-    assert flags.startswith(LATENCY_HIDING_FLAGS)
-    assert flags.endswith("--prior=1")
-    # idempotent
-    assert configure_latency_hiding()
-    assert os.environ["XLA_FLAGS"].count(
-        "--xla_gpu_enable_latency_hiding_scheduler") == 1
-    # opt-outs leave the env untouched
-    monkeypatch.setenv("XLA_FLAGS", "--prior=1")
-    assert not configure_latency_hiding(enable=False)
-    assert os.environ["XLA_FLAGS"] == "--prior=1"
-    monkeypatch.setenv(OPT_OUT_ENV, "1")
-    assert not configure_latency_hiding()
-    assert os.environ["XLA_FLAGS"] == "--prior=1"
+    """Latency hiding is a property of the backend, not of XLA_FLAGS: the
+    TPU compiler overlaps async collectives by default, the CPU backend
+    never does — whatever the environment says."""
+    import jax
+
+    from repro.launch.mesh import latency_hiding_active
+    monkeypatch.setenv(
+        "XLA_FLAGS", "--xla_gpu_enable_latency_hiding_scheduler=true")
+    assert not latency_hiding_active()       # the test session is on CPU
+    monkeypatch.delenv("XLA_FLAGS")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert latency_hiding_active()
 
 
 def test_production_mesh_validates_device_count():
