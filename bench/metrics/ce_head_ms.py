@@ -1,0 +1,8 @@
+"""Device time a step under the program's ``ce_head`` scope, forward,
+backward and recomputation, averaged over the chips, in ms
+(``bench/scopes.py``; None where the trace holds no scopes)."""
+
+
+def read(run):
+    s = run.get("scopes")
+    return s["layers_ms"]["ce_head"] if s and s["scoped"] else None

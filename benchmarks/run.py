@@ -1,4 +1,4 @@
-"""Benchmark entry point: one function per paper table/figure + the roofline.
+"""Benchmark entry point: one function per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows per the harness contract, plus
 the full per-figure records.
@@ -78,19 +78,6 @@ def main() -> int:
         derived = _derived(name, rows) if status == "ok" else status
         print(f"{name},{dt:.0f},{derived}", flush=True)
         all_rows[name] = rows
-
-    # roofline summary (reads the dry-run artifacts if present)
-    t0 = time.perf_counter()
-    try:
-        from .roofline import load_cells
-        rows = load_cells()
-        ok = [r for r in rows if r.status == "ok"]
-        best = max((r.frac_of_roofline for r in ok), default=0)
-        derived = (f"cells={len(rows)};ok={len(ok)};"
-                   f"best_frac={best:.2f}")
-    except Exception as e:  # noqa: BLE001
-        derived = f"unavailable({e!r})"
-    print(f"roofline,{(time.perf_counter() - t0) * 1e6:.0f},{derived}")
 
     # compile-cache statistics across every step built this process
     t0 = time.perf_counter()

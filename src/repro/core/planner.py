@@ -18,7 +18,6 @@ executor's previous step, reproducing the paper's disaggregated architecture.
 from __future__ import annotations
 
 import math
-import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -288,8 +287,14 @@ def estimate_plan_time(cm: CostModel, plan: ExecutionPlan) -> float:
 
 def plan_batch(cm: CostModel, lengths: Sequence[int],
                cfg: Optional[PlannerConfig] = None) -> ExecutionPlan:
-    cfg = cfg or PlannerConfig()
-    t0 = time.perf_counter()
+    # imported here: repro.telemetry imports this module
+    from repro.telemetry.timeline import span
+    with span("repro.plan_batch") as s:
+        return _plan_batch(cm, lengths, cfg or PlannerConfig(), s)
+
+
+def _plan_batch(cm: CostModel, lengths: Sequence[int], cfg: PlannerConfig,
+                clock) -> ExecutionPlan:
     d_p = cm.cluster.d_p
 
     # SP is a plan axis: rank the legal (policy, d_s_eff) candidates by
@@ -327,7 +332,7 @@ def plan_batch(cm: CostModel, lengths: Sequence[int],
         chunk_capacity=cap,
         mesh_slices=chunking.mesh,
         est_total_time=total,
-        solve_time=time.perf_counter() - t0,
+        solve_time=clock.elapsed(),
         remat_mode=cfg.remat_mode,
         schedule=sched_name,
         v_stages=v_stages,

@@ -23,6 +23,7 @@ from typing import Dict, List, Sequence
 import numpy as np
 
 from repro.core.plan import Chunk, ExecutionPlan
+from repro.telemetry.timeline import span
 
 __all__ = ["ChunkBatch", "materialize_plan", "materialize_chunks"]
 
@@ -77,7 +78,8 @@ def materialize_plan(plan: ExecutionPlan, corpus: Dict[int, np.ndarray]
                      ) -> ChunkBatch:
     """All pipelines' chunks concatenated in execution order (gradient
     accumulation across 1F1B pipelines is the concatenated scan)."""
-    chunks: List[Chunk] = []
-    for p in plan.pipelines:
-        chunks.extend(p.chunks)
-    return materialize_chunks(chunks, corpus, plan.chunk_capacity)
+    with span("repro.materialize"):
+        chunks: List[Chunk] = []
+        for p in plan.pipelines:
+            chunks.extend(p.chunks)
+        return materialize_chunks(chunks, corpus, plan.chunk_capacity)

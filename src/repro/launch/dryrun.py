@@ -15,8 +15,7 @@ For each cell this:
      production mesh and calls ``.lower().compile()`` on ShapeDtypeStructs
      (no allocation);
   3. records ``memory_analysis()`` / ``cost_analysis()`` + an HLO collective
-     scan + analytic collective volumes into a JSON cache that
-     benchmarks/roofline.py consumes.
+     scan + analytic collective volumes into a JSON cache.
 
 Usage:
   python -m repro.launch.dryrun --arch llama3.2-3b --shape train_4k

@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import time
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Optional
@@ -140,7 +139,7 @@ def train(cfg_arch, mesh, loop: TrainLoopConfig, *, log=print):
                                store_fingerprint)
     from repro.runtime.sharding import mesh_axis_names
     from repro.telemetry import (ReplanConfig, ReplanController,
-                                 StepTimeline, read_jsonl)
+                                 StepTimeline, read_jsonl, span)
 
     enable_compile_cache()
     pod, data, model = mesh_axis_names(mesh)
@@ -494,20 +493,21 @@ def train(cfg_arch, mesh, loop: TrainLoopConfig, *, log=print):
         bucket = bucket_of(plan)
         batch = mat(plan, corpus, key.cap, key.n_chunks)
         probed = bool(loop.probe_every) and step % loop.probe_every == 0
-        t0 = time.perf_counter()
-        params, opt, _err, metrics = step_fn(params, opt, None, batch)
-        dt_probe = None
-        if probed:
-            # probe mode: block_until_ready brackets the device step so
-            # the sample excludes async dispatch (and the overlapped
-            # solver below); per-stage attribution divides it across the
-            # pipeline — the injector can then skew individual stages
-            jax.block_until_ready(metrics["loss"])
-            dt_probe = time.perf_counter() - t0
-        # overlap: next iteration's plan solves while devices run
-        next_plan, next_corpus = plan_for(step + 1)
-        loss = float(metrics["loss"])
-        dt_step = time.perf_counter() - t0
+        with span("repro.train_step", step_num=step) as s:
+            params, opt, _err, metrics = step_fn(params, opt, None, batch)
+            dt_probe = None
+            if probed:
+                # probe mode: block_until_ready brackets the device step
+                # so the sample excludes async dispatch (and the
+                # overlapped solver below); per-stage attribution divides
+                # it across the pipeline — the injector can then skew
+                # individual stages
+                jax.block_until_ready(metrics["loss"])
+                dt_probe = s.elapsed()
+            # overlap: next iteration's plan solves while devices run
+            next_plan, next_corpus = plan_for(step + 1)
+            loss = float(metrics["loss"])
+        dt_step = s.seconds
         wall = dt_probe if dt_probe is not None else dt_step
         per_stage = None
         wall_rep = wall

@@ -192,23 +192,24 @@ def attention_block(cfg: ArchConfig, p: Dict, x: jnp.ndarray, *,
                     ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray],
                                Optional[jnp.ndarray]]:
     """Returns (block_out [T, D], new_ctx_k, new_ctx_v)."""
-    s = cfg.spec
-    dt = x.dtype
-    T = x.shape[0]
-    q, k_cur, v_cur = project_qkv(cfg, p, x, pos, positions3)
+    with jax.named_scope("attention"):
+        s = cfg.spec
+        dt = x.dtype
+        T = x.shape[0]
+        q, k_cur, v_cur = project_qkv(cfg, p, x, pos, positions3)
 
-    expand_fn = None
-    scale = 1.0 / math.sqrt(s.head_dim)
-    if s.kv_lora_rank > 0:
-        scale = 1.0 / math.sqrt(s.head_dim + s.qk_rope_dim)
-        expand_fn = functools.partial(mla_expand_ctx, cfg, p)
+        expand_fn = None
+        scale = 1.0 / math.sqrt(s.head_dim)
+        if s.kv_lora_rank > 0:
+            scale = 1.0 / math.sqrt(s.head_dim + s.qk_rope_dim)
+            expand_fn = functools.partial(mla_expand_ctx, cfg, p)
 
-    out, new_k, new_v = attn_fn(
-        q, k_cur, v_cur, seg=seg, pos=pos, ctx_k=ctx_k, ctx_v=ctx_v,
-        ctx_len=ctx_len, causal=causal, window=window, scale=scale,
-        expand_fn=expand_fn)
-    if s.kv_lora_rank > 0:
-        out = out[..., :s.head_dim]  # value width (drop rope channels)
-    out = out.reshape(T, -1)
-    y = jnp.einsum("th,hd->td", out, p["wo"].astype(dt))
-    return y, new_k, new_v
+        out, new_k, new_v = attn_fn(
+            q, k_cur, v_cur, seg=seg, pos=pos, ctx_k=ctx_k, ctx_v=ctx_v,
+            ctx_len=ctx_len, causal=causal, window=window, scale=scale,
+            expand_fn=expand_fn)
+        if s.kv_lora_rank > 0:
+            out = out[..., :s.head_dim]  # value width (drop rope channels)
+        out = out.reshape(T, -1)
+        y = jnp.einsum("th,hd->td", out, p["wo"].astype(dt))
+        return y, new_k, new_v

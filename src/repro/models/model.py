@@ -112,9 +112,10 @@ class DecoderLM:
     def embed(self, params: Dict, tokens: jnp.ndarray,
               compute_dtype=jnp.bfloat16) -> jnp.ndarray:
         cfg, s = self.cfg, self.cfg.spec
-        x = params["embed"][tokens].astype(compute_dtype)
-        if cfg.embed_scale:
-            x = x * jnp.asarray(s.d_model ** 0.5, compute_dtype)
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens].astype(compute_dtype)
+            if cfg.embed_scale:
+                x = x * jnp.asarray(s.d_model ** 0.5, compute_dtype)
         return x
 
     def chunk_loss(self, params: Dict, hidden: jnp.ndarray,
@@ -122,10 +123,11 @@ class DecoderLM:
                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """(sum_loss, n_valid) via the streaming fused CE."""
         cfg = self.cfg
-        h = rms_norm(hidden, params["final_norm"], cfg.rms_eps)
-        valid = (seg >= 0) & (targets >= 0)
-        return streaming_cross_entropy(h, self.head_weights(params),
-                                       jnp.maximum(targets, 0), valid)
+        with jax.named_scope("ce_head"):
+            h = rms_norm(hidden, params["final_norm"], cfg.rms_eps)
+            valid = (seg >= 0) & (targets >= 0)
+            return streaming_cross_entropy(h, self.head_weights(params),
+                                           jnp.maximum(targets, 0), valid)
 
     # ------------------------------------------------------------------
     # One layer (the unit the pipeline scans).
@@ -176,7 +178,8 @@ class DecoderLM:
             if s.n_experts > 0:
                 x = x + self.moe_fn(cfg, lparams["moe"], h2)
             else:
-                x = x + swiglu_apply(lparams["mlp"], h2)
+                with jax.named_scope("mlp"):
+                    x = x + swiglu_apply(lparams["mlp"], h2)
         return x, LayerCtx(new_k, new_v, new_h, new_tail)
 
     # ------------------------------------------------------------------

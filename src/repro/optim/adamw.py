@@ -74,39 +74,42 @@ def adamw_update(cfg: AdamWConfig, params, grads, state, *,
     """One AdamW step on local shards. ``grad_scale`` rescales summed-loss
     gradients to per-token means (1 / n_valid_tokens). Callers inside
     shard_map pass a precomputed ``gnorm`` (replication-factor aware)."""
-    step = state["step"] + 1
-    grads = jax.tree.map(lambda g: g.astype(jnp.float32) * grad_scale, grads)
-    if gnorm is None:
-        gnorm = global_norm(grads, psum_axes)
-    else:
-        gnorm = gnorm * grad_scale
-    clip = jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gnorm, 1e-12))
-    lr = lr_schedule(cfg, step)
-    b1, b2 = cfg.beta1, cfg.beta2
-    c1 = 1.0 - b1 ** step.astype(jnp.float32)
-    c2 = 1.0 - b2 ** step.astype(jnp.float32)
+    with jax.named_scope("optimizer"):
+        step = state["step"] + 1
+        grads = jax.tree.map(
+            lambda g: g.astype(jnp.float32) * grad_scale, grads)
+        if gnorm is None:
+            gnorm = global_norm(grads, psum_axes)
+        else:
+            gnorm = gnorm * grad_scale
+        clip = jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gnorm, 1e-12))
+        lr = lr_schedule(cfg, step)
+        b1, b2 = cfg.beta1, cfg.beta2
+        c1 = 1.0 - b1 ** step.astype(jnp.float32)
+        c2 = 1.0 - b2 ** step.astype(jnp.float32)
 
-    def upd(p_master, g, m, v):
-        g = g * clip
-        m = b1 * m + (1 - b1) * g
-        v = b2 * v + (1 - b2) * jnp.square(g)
-        mh = m / c1
-        vh = v / c2
-        new_master = p_master - lr * (
-            mh / (jnp.sqrt(vh) + cfg.eps) + cfg.weight_decay * p_master)
-        return new_master, m, v
+        def upd(p_master, g, m, v):
+            g = g * clip
+            m = b1 * m + (1 - b1) * g
+            v = b2 * v + (1 - b2) * jnp.square(g)
+            mh = m / c1
+            vh = v / c2
+            new_master = p_master - lr * (
+                mh / (jnp.sqrt(vh) + cfg.eps) + cfg.weight_decay * p_master)
+            return new_master, m, v
 
-    flat_p, treedef = jax.tree.flatten(state["master"])
-    flat_g = jax.tree.leaves(grads)
-    flat_m = jax.tree.leaves(state["m"])
-    flat_v = jax.tree.leaves(state["v"])
-    out = [upd(p, g, m, v)
-           for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v)]
-    new_master = jax.tree.unflatten(treedef, [o[0] for o in out])
-    new_m = jax.tree.unflatten(treedef, [o[1] for o in out])
-    new_v = jax.tree.unflatten(treedef, [o[2] for o in out])
-    new_params = jax.tree.map(
-        lambda live, mast: mast.astype(live.dtype), params, new_master)
-    new_state = {"master": new_master, "m": new_m, "v": new_v, "step": step}
-    metrics = {"grad_norm": gnorm, "lr": lr}
-    return new_params, new_state, metrics
+        flat_p, treedef = jax.tree.flatten(state["master"])
+        flat_g = jax.tree.leaves(grads)
+        flat_m = jax.tree.leaves(state["m"])
+        flat_v = jax.tree.leaves(state["v"])
+        out = [upd(p, g, m, v)
+               for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v)]
+        new_master = jax.tree.unflatten(treedef, [o[0] for o in out])
+        new_m = jax.tree.unflatten(treedef, [o[1] for o in out])
+        new_v = jax.tree.unflatten(treedef, [o[2] for o in out])
+        new_params = jax.tree.map(
+            lambda live, mast: mast.astype(live.dtype), params, new_master)
+        new_state = {"master": new_master, "m": new_m, "v": new_v,
+                     "step": step}
+        metrics = {"grad_norm": gnorm, "lr": lr}
+        return new_params, new_state, metrics

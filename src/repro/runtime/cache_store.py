@@ -32,7 +32,8 @@ Invalidation rules — a stale entry is SKIPPED, never loaded wrong:
 
 * **fingerprint mismatch**: every entry records the store's fingerprint
   (mesh axes+shape, device count, backend platform, jax version, ModelSpec
-  hash, compute dtype — see :func:`store_fingerprint`). ``load`` compares
+  hash, compute dtype, a hash of the program's source — see
+  :func:`store_fingerprint`). ``load`` compares
   the entry's recorded fingerprint against the current store's, field by
   field; any difference (e.g. the elastic demo's mesh change) counts as a
   ``stale_skips`` and falls back to cold compile.
@@ -48,6 +49,7 @@ no half-written entry that a later run could trip over.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import json
 import os
@@ -57,7 +59,7 @@ from pathlib import Path
 from typing import Any, Callable, Dict, Hashable, List, Optional
 
 __all__ = ["CacheStore", "StoreStats", "model_fingerprint",
-           "store_fingerprint"]
+           "source_fingerprint", "store_fingerprint"]
 
 _FORMAT_VERSION = 1
 
@@ -75,6 +77,21 @@ def model_fingerprint(spec) -> str:
     return hashlib.sha256(blob.encode()).hexdigest()[:16]
 
 
+@functools.lru_cache(maxsize=None)
+def source_fingerprint(package_dir: Optional[str] = None) -> str:
+    """Hash of every ``.py`` file under ``package_dir`` (default: the
+    ``repro`` package), paths and bytes: an executable compiled by other
+    code — another commit, a patched checkout — reads as stale even where
+    the bucket key and topology agree (its program differs, if only in
+    its op metadata)."""
+    root = Path(package_dir or Path(__file__).resolve().parents[1])
+    h = hashlib.sha256()
+    for path in sorted(root.rglob("*.py")):
+        h.update(path.relative_to(root).as_posix().encode() + b"\0")
+        h.update(path.read_bytes() + b"\0")
+    return h.hexdigest()[:16]
+
+
 def store_fingerprint(mesh=None, *, spec=None, compute_dtype=None,
                       extra: Optional[Dict[str, Any]] = None
                       ) -> Dict[str, Any]:
@@ -83,7 +100,8 @@ def store_fingerprint(mesh=None, *, spec=None, compute_dtype=None,
     Captures everything that changes the compiled HLO *outside* the bucket
     key: mesh axes and shape (an elastic reshard invalidates), total device
     count, backend platform, jax/jaxlib versions (XLA output is not stable
-    across releases), the ModelSpec hash and the compute dtype.
+    across releases), the ModelSpec hash, the compute dtype and the hash
+    of the program's source.
     """
     import jax
 
@@ -92,6 +110,7 @@ def store_fingerprint(mesh=None, *, spec=None, compute_dtype=None,
         "jax": jax.__version__,
         "backend": jax.default_backend(),
         "n_devices": jax.device_count(),
+        "source": source_fingerprint(),
     }
     try:
         import jaxlib

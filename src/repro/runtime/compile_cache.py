@@ -42,11 +42,12 @@ so they cannot grow without bound.
 from __future__ import annotations
 
 import threading
-import time
 import weakref
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Hashable, Optional, Set, Tuple
+
+from repro.telemetry.timeline import span
 
 __all__ = ["CacheStats", "CompileCache", "decode_bucket_key",
            "engine_bucket_key", "engine_copy_bucket_key",
@@ -219,10 +220,10 @@ class CompileCache:
                 return self._entries[key]
 
         if self.store is not None:
-            t0 = time.perf_counter()
-            value = self.store.load(key)
+            with span("repro.store_load", bucket=str(key)) as sp:
+                value = self.store.load(key)
             if value is not None:
-                dt = time.perf_counter() - t0
+                dt = sp.seconds
                 with self._lock:
                     if key in self._entries:  # raced with another loader
                         self.stats.hits += 1
@@ -258,9 +259,9 @@ class CompileCache:
                 # growing this set for the life of the cache — recompiles
                 # become a lower bound instead of a leak
                 self._compiled_keys.add(key)
-        t0 = time.perf_counter()
-        value = build()
-        dt = time.perf_counter() - t0
+        with span("repro.compile", bucket=str(key)) as sp:
+            value = build()
+        dt = sp.seconds
         if self.lint is not None:
             # audit the newborn program BEFORE it becomes reusable state:
             # in error mode the hook raises and the executable is neither

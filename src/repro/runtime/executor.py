@@ -127,8 +127,9 @@ def ppermute_streams(streams, data_axis: str, d_p: int, *,
         return streams
     from repro.core.schedule import stream_perm
     perm = stream_perm(d_p, ring=ring)
-    return jax.tree.map(
-        lambda x: jax.lax.ppermute(x, data_axis, perm), streams)
+    with jax.named_scope("handoff"):
+        return jax.tree.map(
+            lambda x: jax.lax.ppermute(x, data_axis, perm), streams)
 
 
 # ---------------------------------------------------------------------------
@@ -311,6 +312,12 @@ def run_stage_program(program: StageProgram, init_streams, init_state,
       :func:`split_backward_stage`. In the transpose the W ticks run
       after the whole B-grad scan — the cooldown drain.
     """
+    with jax.named_scope("stage_tick"):
+        return _run_stage_program(program, init_streams, init_state,
+                                  init_acc)
+
+
+def _run_stage_program(program, init_streams, init_state, init_acc):
     n, d_p, v = program.n_items, program.d_p, program.v
     n_groups = program.spec.n_groups(n, d_p)
     split = program.split_bwd

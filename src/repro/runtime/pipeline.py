@@ -282,8 +282,9 @@ def pipeline_loss_fn(cfg: ArchConfig, geom: PipelineGeometry,
         # final-norm gamma may be feature-sharded; gather once
         fn_gamma = params["final_norm"]
         if fn_gamma.shape[0] != s.d_model:
-            fn_gamma = jax.lax.all_gather(fn_gamma, model_axis, axis=0,
-                                          tiled=True)
+            with jax.named_scope("ce_head"):
+                fn_gamma = jax.lax.all_gather(fn_gamma, model_axis, axis=0,
+                                              tiled=True)
         head_w = params.get("unembed", params["embed"])
 
         ctx0 = init_stage_ctx(cfg, geom)
@@ -295,7 +296,11 @@ def pipeline_loss_fn(cfg: ArchConfig, geom: PipelineGeometry,
         remat_head = (geom.ckpt_table is None
                       and geom.l_ckpt >= geom.layers_per_stage)
 
-        def fold_acc(tc, x_out, ctx, acc):  # noqa: ARG001 (ctx unused)
+        def fold_acc(tc, x_out, ctx, acc):
+            with jax.named_scope("ce_head"):
+                return _fold_acc(tc, x_out, ctx, acc)
+
+        def _fold_acc(tc, x_out, ctx, acc):  # noqa: ARG001 (ctx unused)
             """Fold one tick's output into the accumulator (CE / greedy
             ids). With ``overlap_handoff`` the executor calls this AFTER
             issuing the stream ppermute, so the vocab-parallel matmul here
@@ -360,6 +365,10 @@ def pipeline_loss_fn(cfg: ArchConfig, geom: PipelineGeometry,
                     i32("start") if "start" in af else None)
 
         def tick(tc, x_recv, ctx, acc, stash=None):
+            with jax.named_scope("stage_tick"):
+                return _tick(tc, x_recv, ctx, acc, stash)
+
+        def _tick(tc, x_recv, ctx, acc, stash):
             tokens = tokens_a[tc.idxc]
             seg = jnp.where(tc.valid, seg_a[tc.idxc], -1)
             pos = pos_a[tc.idxc]
@@ -462,6 +471,10 @@ def pipeline_loss_fn(cfg: ArchConfig, geom: PipelineGeometry,
             return x_out, ctx, acc
 
         def drain_tick(j, entry, sp_full, af):
+            with jax.named_scope("stage_tick"):
+                return _drain_tick(j, entry, sp_full, af)
+
+        def _drain_tick(j, entry, sp_full, af):
             """W-grad tick ``j`` (transposed cooldown): replay stage
             weight grads for item ``j % n`` / virtual block ``j // n``
             from the stashed ``(x_in, ctx_in, ybar, ctx_bar)`` boundary

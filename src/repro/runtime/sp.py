@@ -86,14 +86,11 @@ def make_allgather_kv_policy(axis: str, flash=None, *,
     def policy(q, k_cur, v_cur, *, seg, pos, ctx_k, ctx_v, ctx_len,
                causal, window, scale, expand_fn=None):
         # gather the current chunk's KV rows (or MLA cache rows) + metadata
-        k_g = jax.lax.all_gather(k_cur, axis, axis=0, tiled=True,
-                                 axis_index_groups=groups)
-        v_g = jax.lax.all_gather(v_cur, axis, axis=0, tiled=True,
-                                 axis_index_groups=groups)
-        seg_g = jax.lax.all_gather(seg, axis, axis=0, tiled=True,
+        with jax.named_scope("sp_collective"):
+            k_g, v_g, seg_g, pos_g = (
+                jax.lax.all_gather(t, axis, axis=0, tiled=True,
                                    axis_index_groups=groups)
-        pos_g = jax.lax.all_gather(pos, axis, axis=0, tiled=True,
-                                   axis_index_groups=groups)
+                for t in (k_cur, v_cur, seg, pos))
         # MLA ships zero-width v (values live in the latent cache rows);
         # ONE condition gates both the attend-path concat and the
         # update-path write so the two can never disagree on what counts
@@ -135,16 +132,15 @@ def make_ulysses_policy(axis: str, d_s: int, flash=None, *,
                causal, window, scale, expand_fn=None):
         assert expand_fn is None, "MLA uses the allgather_kv policy"
         # tokens -> full sequence, heads -> sharded (4 a2a's: q, k, v, out)
-        q_g = jax.lax.all_to_all(q, axis, split_axis=1, concat_axis=0,
-                                 tiled=True, axis_index_groups=groups)
-        k_g = jax.lax.all_to_all(k_cur, axis, split_axis=1, concat_axis=0,
-                                 tiled=True, axis_index_groups=groups)
-        v_g = jax.lax.all_to_all(v_cur, axis, split_axis=1, concat_axis=0,
-                                 tiled=True, axis_index_groups=groups)
-        seg_g = jax.lax.all_gather(seg, axis, axis=0, tiled=True,
+        with jax.named_scope("sp_collective"):
+            q_g, k_g, v_g = (
+                jax.lax.all_to_all(t, axis, split_axis=1, concat_axis=0,
+                                   tiled=True, axis_index_groups=groups)
+                for t in (q, k_cur, v_cur))
+            seg_g, pos_g = (
+                jax.lax.all_gather(t, axis, axis=0, tiled=True,
                                    axis_index_groups=groups)
-        pos_g = jax.lax.all_gather(pos, axis, axis=0, tiled=True,
-                                   axis_index_groups=groups)
+                for t in (seg, pos))
         if ctx_k is not None:
             # context is head-sharded: concat along the sequence dim
             C_cap = ctx_k.shape[0]
@@ -163,8 +159,10 @@ def make_ulysses_policy(axis: str, d_s: int, flash=None, *,
             new_k = new_v = None
         out_g = flash(q_g, kk, vv, seg_g, kv_seg, pos_g, kv_pos,
                       causal=causal, window=window, scale=scale)
-        out = jax.lax.all_to_all(out_g, axis, split_axis=0, concat_axis=1,
-                                 tiled=True, axis_index_groups=groups)
+        with jax.named_scope("sp_collective"):
+            out = jax.lax.all_to_all(out_g, axis, split_axis=0,
+                                     concat_axis=1, tiled=True,
+                                     axis_index_groups=groups)
         return out, new_k, new_v
 
     return policy
@@ -183,14 +181,16 @@ def sharded_embed(embed_local: jnp.ndarray, tokens: jnp.ndarray, axis: str,
     device looks the full chunk up in its vocab shard, and the partial rows
     reduce-scatter back to token shards — one collective each way.
     """
-    ids = jax.lax.all_gather(tokens, axis, axis=0, tiled=True)   # [cap]
-    vs = embed_local.shape[0]
-    off = jax.lax.axis_index(axis) * vs
-    loc = ids - off
-    ok = (loc >= 0) & (loc < vs)
-    rows = embed_local[jnp.clip(loc, 0, vs - 1)].astype(compute_dtype)
-    rows = jnp.where(ok[:, None], rows, 0)
-    return jax.lax.psum_scatter(rows, axis, scatter_dimension=0, tiled=True)
+    with jax.named_scope("embed"):
+        ids = jax.lax.all_gather(tokens, axis, axis=0, tiled=True)  # [cap]
+        vs = embed_local.shape[0]
+        off = jax.lax.axis_index(axis) * vs
+        loc = ids - off
+        ok = (loc >= 0) & (loc < vs)
+        rows = embed_local[jnp.clip(loc, 0, vs - 1)].astype(compute_dtype)
+        rows = jnp.where(ok[:, None], rows, 0)
+        return jax.lax.psum_scatter(rows, axis, scatter_dimension=0,
+                                    tiled=True)
 
 
 def sharded_ce(hidden_local: jnp.ndarray, w_local: jnp.ndarray,
@@ -205,9 +205,10 @@ def sharded_ce(hidden_local: jnp.ndarray, w_local: jnp.ndarray,
     per-token stats merge with a distributed LSE.
     Returns (sum_loss, n_valid) REPLICATED across ``axis``.
     """
-    h_g = jax.lax.all_gather(hidden_local, axis, axis=0, tiled=True)
-    t_g = jax.lax.all_gather(targets_local, axis, axis=0, tiled=True)
-    v_g = jax.lax.all_gather(valid_local, axis, axis=0, tiled=True)
+    with jax.named_scope("sp_collective"):
+        h_g, t_g, v_g = (
+            jax.lax.all_gather(t, axis, axis=0, tiled=True)
+            for t in (hidden_local, targets_local, valid_local))
     vs = w_local.shape[0]
     off = jax.lax.axis_index(axis) * vs
     m, l, tgt = streaming_ce_stats(h_g, w_local, t_g - off,
@@ -215,9 +216,11 @@ def sharded_ce(hidden_local: jnp.ndarray, w_local: jnp.ndarray,
                                    vocab_true=vocab_true)
     # the max-shift is pure numerics: logsumexp is shift-invariant, so a
     # stop_gradient keeps the backward exact (and pmax has no grad rule).
-    m_g = jax.lax.stop_gradient(jax.lax.pmax(jax.lax.stop_gradient(m), axis))
-    l_g = jax.lax.psum(l * jnp.exp(m - m_g), axis)
-    tgt_g = jax.lax.psum(tgt, axis)
+    with jax.named_scope("sp_collective"):
+        m_g = jax.lax.stop_gradient(
+            jax.lax.pmax(jax.lax.stop_gradient(m), axis))
+        l_g = jax.lax.psum(l * jnp.exp(m - m_g), axis)
+        tgt_g = jax.lax.psum(tgt, axis)
     lse = m_g + jnp.log(jnp.maximum(l_g, 1e-30))
     loss = jnp.where(v_g, lse - tgt_g, 0.0)
     return loss.sum(), v_g.astype(jnp.float32).sum()
@@ -287,9 +290,10 @@ def make_sp_ssm_scan(axis: str, d_s: int, local_scan, *,
         zeros = jnp.zeros_like(h0)
         _, h_last0 = local_scan(a, bx, zeros)
         a_prod = jnp.prod(a, axis=0)  # elementwise — resets (a=0) propagate
-        summ = jax.lax.all_gather(
-            jnp.stack([a_prod, h_last0]), axis,
-            axis_index_groups=groups)                    # [d_s, 2, di, ds]
+        with jax.named_scope("sp_collective"):
+            summ = jax.lax.all_gather(
+                jnp.stack([a_prod, h_last0]), axis,
+                axis_index_groups=groups)                # [d_s, 2, di, ds]
         my = jax.lax.axis_index(axis) // rep
 
         def fold(carry, i):
@@ -336,9 +340,10 @@ def make_sp_conv_tail_exchange(axis: str, d_s: int, *,
         K1 = carried_tail.shape[0]
         my_tail = jax.lax.dynamic_slice_in_dim(
             xs, xs.shape[0] - K1, K1, axis=0)
-        from_left = jax.lax.ppermute(my_tail, axis, shift)
-        prev_chunk = jax.lax.ppermute(carried_tail.astype(xs.dtype), axis,
-                                      wrap)
+        with jax.named_scope("sp_collective"):
+            from_left = jax.lax.ppermute(my_tail, axis, shift)
+            prev_chunk = jax.lax.ppermute(carried_tail.astype(xs.dtype),
+                                          axis, wrap)
         my = jax.lax.axis_index(axis) // rep
         return jnp.where(my == 0, prev_chunk, from_left)
 

@@ -238,7 +238,8 @@ class TrainStepBuilder:
                 grads = jax.lax.psum(grads, self.pod_axis)
 
         grad_scale = 1.0 / jnp.maximum(n_valid, 1.0)
-        gnorm = self._global_gnorm(grads, norm_factors)
+        with jax.named_scope("optimizer"):
+            gnorm = self._global_gnorm(grads, norm_factors)
         new_params, new_opt, metrics = adamw_update(
             self.adamw, params, grads, opt_state, grad_scale=grad_scale,
             gnorm=gnorm)

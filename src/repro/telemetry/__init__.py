@@ -19,7 +19,7 @@ from .calibrate import (CostCalibration, Cusum, MixTracker, StepSample,
                         plan_components, predicted_work)
 from .replan import ReplanConfig, ReplanController, ReplanDecision
 from .stats_io import atomic_write_json, read_json, read_jsonl
-from .timeline import StepEvent, StepTimeline
+from .timeline import StepEvent, StepTimeline, span
 
 __all__ = [
     "CostCalibration", "Cusum", "MixTracker", "StepSample",
@@ -27,5 +27,5 @@ __all__ = [
     "predicted_work",
     "ReplanConfig", "ReplanController", "ReplanDecision",
     "atomic_write_json", "read_json", "read_jsonl",
-    "StepEvent", "StepTimeline",
+    "StepEvent", "StepTimeline", "span",
 ]

@@ -18,6 +18,11 @@ Event kinds (the schema documented in runtime/README.md):
 * ``engine``       — serve-engine sample: TTFT/TPOT percentiles, occupancy.
 * ``calibration``  — a new CostCalibration version was adopted.
 * ``replan``       — re-plan trigger / decision / swap (replan.py).
+
+``span`` is the program's host span on the profiler's clock: a
+``jax.profiler.TraceAnnotation`` (``StepTraceAnnotation`` with a
+``step_num``) that also times its body with ``time.perf_counter``, so a
+timeline event and the profiler's span report one reading.
 """
 
 from __future__ import annotations
@@ -28,9 +33,56 @@ from collections import defaultdict, deque
 from pathlib import Path
 from typing import Any, Dict, List, Optional
 
-__all__ = ["StepEvent", "StepTimeline"]
+__all__ = ["StepEvent", "StepTimeline", "span"]
 
 EMA_DECAY = 0.3  # weight of the newest sample in the per-bucket step EMA
+
+
+class span:
+    """``with span("repro.<name>", **args) as s:`` — a profiler span named
+    ``name`` around the body; ``s.elapsed()`` inside and ``s.seconds``
+    after it are ``perf_counter`` readings from the span's own start.
+
+    ``step_num`` in ``args`` makes it a ``StepTraceAnnotation`` (a step
+    marker for the profiler's step view). JAX is imported on first
+    entry, so this module stays importable without it; without JAX the
+    span only times. Names start with ``repro.``: the benchmark's own
+    spans (``window``, ``plan``, ...) keep names of their own.
+    """
+
+    __slots__ = ("name", "args", "t0", "seconds", "_ann")
+
+    def __init__(self, name: str, **args: Any) -> None:
+        if not name.startswith("repro."):
+            raise ValueError(f"program span names start with 'repro.', "
+                             f"got {name!r}")
+        self.name, self.args = name, args
+        self.seconds = 0.0
+
+    def __enter__(self) -> "span":
+        self._ann = _annotation(self.name, self.args)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def elapsed(self) -> float:
+        return time.perf_counter() - self.t0
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = self.elapsed()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+
+
+def _annotation(name: str, args: Dict[str, Any]):
+    try:
+        from jax import profiler
+    except ImportError:
+        return None
+    if "step_num" in args:
+        return profiler.StepTraceAnnotation(name, **args)
+    return profiler.TraceAnnotation(name, **args)
 
 
 class StepEvent(dict):

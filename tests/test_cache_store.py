@@ -214,6 +214,40 @@ def test_stale_fingerprint_skipped_with_cold_fallback(tmp_path):
     assert store_a2.report()["entries"] == 2
 
 
+def test_changed_source_makes_entries_stale(tmp_path):
+    """The fingerprint carries a hash of the package's source: an entry
+    written by other code (any ``.py`` file changed, added or renamed)
+    is a stale skip, not a load."""
+    from repro.runtime.cache_store import source_fingerprint
+    pkg = tmp_path / "pkg"
+    (pkg / "sub").mkdir(parents=True)
+    (pkg / "a.py").write_text("x = 1\n")
+    (pkg / "sub" / "b.py").write_text("y = 2\n")
+    (pkg / "notes.txt").write_text("not source")
+    before = source_fingerprint(str(pkg))
+    assert source_fingerprint(str(pkg)) == before
+    (pkg / "notes.txt").write_text("still not source")
+    source_fingerprint.cache_clear()
+    assert source_fingerprint(str(pkg)) == before
+    (pkg / "sub" / "b.py").write_text("y = 3\n")
+    source_fingerprint.cache_clear()
+    after = source_fingerprint(str(pkg))
+    assert after != before
+    assert store_fingerprint()["source"] == source_fingerprint()
+
+    key = ("bucket", 2)
+    store_old = CacheStore(tmp_path / "store",
+                           store_fingerprint(extra={"source": before}))
+    CompileCache(name="old", store=store_old).get(
+        key, lambda: _compile_toy_step(1.0))
+    store_new = CacheStore(tmp_path / "store",
+                           store_fingerprint(extra={"source": after}))
+    built = []
+    CompileCache(name="new", store=store_new).get(
+        key, lambda: built.append(1) or _compile_toy_step(1.0))
+    assert built == [1] and store_new.stats.stale_skips == 1
+
+
 def test_fingerprint_with_non_json_native_values_round_trips(tmp_path):
     """Tuples and arbitrary objects in the fingerprint must not (a) crash
     save()'s sidecar dump or (b) read back permanently stale because the

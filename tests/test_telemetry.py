@@ -65,6 +65,50 @@ def test_timeline_spill_failure_never_raises(tmp_path):
 # Atomic stats writes
 # ---------------------------------------------------------------------------
 
+def test_span_times_its_body_and_names_the_profiler_span(monkeypatch):
+    """``span`` enters a ``TraceAnnotation`` (``StepTraceAnnotation`` with
+    a ``step_num``) and reports the body's ``perf_counter`` reading."""
+    from jax import profiler
+
+    from repro.telemetry import span
+    seen = []
+
+    class Ann:
+        def __init__(self, name, **args):
+            seen.append((type(self).__name__, name, args))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            seen.append("exit")
+
+    class StepAnn(Ann):
+        pass
+
+    monkeypatch.setattr(profiler, "TraceAnnotation", Ann)
+    monkeypatch.setattr(profiler, "StepTraceAnnotation", StepAnn)
+    with span("repro.plan_batch", bucket="b") as s:
+        time.sleep(0.01)
+        inside = s.elapsed()
+    assert 0.01 <= inside <= s.seconds
+    with span("repro.train_step", step_num=3):
+        pass
+    assert seen == [("Ann", "repro.plan_batch", {"bucket": "b"}), "exit",
+                    ("StepAnn", "repro.train_step", {"step_num": 3}),
+                    "exit"]
+    with pytest.raises(ValueError):
+        span("plan")
+
+
+def test_span_times_without_jax(monkeypatch):
+    from repro.telemetry import span
+    monkeypatch.setitem(sys.modules, "jax", None)  # import jax fails
+    with span("repro.materialize") as s:
+        pass
+    assert s.seconds >= 0.0
+
+
 def test_atomic_write_and_read(tmp_path):
     p = tmp_path / "stats.json"
     atomic_write_json(p, {"a": 1})
