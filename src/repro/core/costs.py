@@ -47,6 +47,9 @@ BWD_MULT = 2.0  # backward flops / forward flops
 # bytes per parameter held through a step — bf16 weights, f32 master,
 # Adam m and v, plus f32 gradients and the update's temporaries
 STEP_BYTES_PER_PARAM = 17.0
+# of those, the model states alone: bf16 weights 2, f32 master 4, Adam m
+# and v 8 (the step's argument bytes on the v5e, exactly)
+STATE_BYTES_PER_PARAM = 14.0
 # compiled residual bytes over the analytic count (layout padding, fusion
 # temporaries)
 STEP_RESIDUAL_SCALE = 1.2
@@ -489,6 +492,15 @@ class CostModel:
         residuals, and the f32 probabilities of one KV tile). With
         ``v_stages`` virtual stages a tick runs ``1/v`` of the stage's
         layers.
+
+        A scan of one tick (one chunk at ``d_p = 1``) is no loop: the
+        compiler inlines it, and each residual dies as the backward passes
+        its layer. The peak is then the larger of the backward's start
+        (the model states, the head's weight gradient and the tick's
+        residuals) and the optimizer's (the states with every gradient):
+        within 9% of the v5e compiler's peak at Qwen3-4B and llama3.2-3b
+        widths, 1024 to 9216 tokens, every depth; up to 19% off either
+        way at 12288 (PERF.md).
         """
         m, cl = self.model, self.cluster
         e, D = m.bytes_per_act, m.d_model
@@ -507,6 +519,10 @@ class CostModel:
             tick += (e if l_ckpt >= l_stage else HEAD_RESIDUAL) * D * toks
         n_embed = m.vocab * D * (1 if m.tie_embeddings else 2)
         params = ((m.param_count() - n_embed) / cl.d_p + n_embed) / cl.d_s
+        if n_ticks == 1:
+            head_grad = e * m.vocab * D / cl.d_s
+            return max(params * STEP_BYTES_PER_PARAM,
+                       params * STATE_BYTES_PER_PARAM + head_grad + tick)
         # a device's query rows times heads is n_heads * toks under every
         # SP policy (ulysses shards heads, the others shard tokens)
         working = 0.5 * r_full + 4.0 * m.n_heads * ATTN_BLOCK_KV * toks

@@ -426,6 +426,19 @@ class PipelinePlan:
         )
 
 
+def round_chunk_count(n: int, chunk_rounding: int = 8) -> int:
+    """A bucket's chunk count for ``n`` real chunks: the next power of two
+    while ``n <= chunk_rounding`` (never past ``chunk_rounding``), the next
+    multiple of ``chunk_rounding`` above it. Never more slots than the
+    plain multiple, never more than twice ``n``, and identical to the
+    plain multiple from ``chunk_rounding`` chunks up."""
+    if n > chunk_rounding:
+        return -(-n // chunk_rounding) * chunk_rounding
+    if n <= 0:
+        return 0
+    return min(1 << (n - 1).bit_length(), chunk_rounding)
+
+
 class BucketKey(NamedTuple):
     """Compiled-executable bucket identity (``ExecutionPlan.bucket_key``).
 
@@ -438,7 +451,8 @@ class BucketKey(NamedTuple):
 
     schedule: str       # schedule backend name (leads: layout is schedule-shaped)
     v_stages: int       # virtual stages per device (interleaved-1f1b)
-    n_chunks: int       # chunk count rounded UP to chunk_rounding
+    n_chunks: int       # chunk count rounded UP: a power of two up to
+                        # chunk_rounding, a multiple of it above
     cap: int            # chunk token capacity rounded up to d_s
     ctx_cap: int        # context capacity rounded up to cap
     l_ckpt: int         # max ILP recompute depth baked into the step
@@ -579,8 +593,12 @@ class ExecutionPlan:
         geometry but not on schedule must NOT share an executable (a
         cross-schedule cache hit would run the wrong program).
 
-        n_chunks rounds UP to a multiple of ``chunk_rounding`` (padding
-        chunks are fully masked — zero loss/grad), cap to the SP degree
+        n_chunks rounds UP (padding chunks are fully masked — zero
+        loss/grad): to a power of two while it is at most
+        ``chunk_rounding`` (1, 2, 4, 8 at the default), to a multiple of
+        ``chunk_rounding`` above it. A small plan so never computes more
+        than twice its real chunks, and a plan of ``chunk_rounding`` or
+        more chunks keys as a plain multiple. cap rounds to the SP degree
         ``d_s`` (token sharding), and ctx_cap to the capacity, so
         consecutive iterations reuse one compiled executable
         (runtime/compile_cache.py).
@@ -601,7 +619,7 @@ class ExecutionPlan:
         compile the same program and share one bucket.
         """
         chunks = [c for p in self.pipelines for c in p.chunks]
-        n = -(-len(chunks) // chunk_rounding) * chunk_rounding
+        n = round_chunk_count(len(chunks), chunk_rounding)
         # the quantum itself must respect d_s alignment or cap would break
         # token sharding (cap_loc = cap // d_s)
         q = -(-max(d_s, cap_quantum) // d_s) * d_s
