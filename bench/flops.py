@@ -8,30 +8,32 @@ multiply-adds of QK^T and PV over each document's causal triangle (2
 products, 2 FLOPs each, 3x for forward and backward). A document of n
 tokens carries n - 1 targets; its last token feeds no loss, so it is not
 counted. Recomputation, padding slots and masked tiles are not counted.
+
+The count of a step is its family's (``step_flops`` of the program
+adapter that the configuration names, ``bench/configs/__init__.py``);
+the output head's is the same in every family.
 """
 
 from __future__ import annotations
 
 from typing import Dict, Iterable
 
-
-def matmul_params(cfg: Dict) -> int:
-    d, hq, hkv, dh, f, v, n = (int(cfg[k]) for k in (
-        "hidden_size", "num_attention_heads", "num_key_value_heads",
-        "head_dim", "intermediate_size", "vocab_size", "num_hidden_layers"))
-    per_layer = d * hq * dh + 2 * d * hkv * dh + hq * dh * d + 3 * d * f
-    return n * per_layer + v * d
+import configs
 
 
 def step_flops(cfg: Dict, doc_lengths: Iterable[int]) -> float:
-    hq, dh, n_layers = (int(cfg[k]) for k in (
-        "num_attention_heads", "head_dim", "num_hidden_layers"))
-    p = matmul_params(cfg)
-    total = 0.0
-    for n in doc_lengths:
-        t = n - 1
-        if t <= 0:
-            continue
-        pairs = t * (t + 1) / 2
-        total += 6.0 * p * t + 3 * 2 * 2 * pairs * hq * dh * n_layers
-    return total
+    return configs.program(cfg).step_flops(cfg, doc_lengths)
+
+
+def head_flops(cfg: Dict, doc_lengths: Iterable[int]) -> float:
+    """The output head's share of a step: 6 x ``hidden_size`` x
+    ``vocab_size`` per target-bearing token."""
+    return (6.0 * int(cfg["hidden_size"]) * int(cfg["vocab_size"])
+            * sum(max(n - 1, 0) for n in doc_lengths))
+
+
+def head_bytes(cfg: Dict) -> float:
+    """The least HBM traffic of the output head in a step: its
+    ``vocab_size`` x ``hidden_size`` matrix read in the forward and the
+    backward and its gradient written once, at 2 bytes an element."""
+    return 3 * 2.0 * int(cfg["hidden_size"]) * int(cfg["vocab_size"])

@@ -29,9 +29,11 @@ for _p in (str(BENCH), str(ROOT / "src")):
         sys.path.insert(0, _p)
 
 import check  # noqa: E402
+import configs  # noqa: E402
 import devtrace  # noqa: E402
 import flops  # noqa: E402
 import peaks  # noqa: E402
+import scopes  # noqa: E402
 import steppath  # noqa: E402
 from traffic import gen  # noqa: E402
 
@@ -58,13 +60,18 @@ class Cell:
         return importlib.import_module(f"configs.{self.config['reference']}")
 
     @property
+    def program(self):
+        return configs.program(self.config)
+
+    @property
     def mesh_shape(self):
         return tuple(self.config["mesh"][str(self.chips)])
 
 
 def resolve(name: str, root: Path = ROOT) -> Cell:
-    """The cell ``name`` of ``BENCHMARK.json`` with its configuration,
-    traffic mix, limits and metrics."""
+    """The cell ``name`` of ``BENCHMARK.json`` under ``root`` with its
+    configuration, traffic mix, limits and metrics; raises at once where
+    the configuration's program adapter is missing or incomplete."""
     man = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in man["workloads"]}
     if name not in cells:
@@ -72,14 +79,15 @@ def resolve(name: str, root: Path = ROOT) -> Cell:
     w = cells[name]
     conf = {c["name"]: c for c in man["configs"]}[w["config"]]
     cfg = json.loads((root / conf["file"]).read_text())
+    configs.program(cfg)
 
     def applies(m):
         return name in m.get("workloads", [name])
 
     return Cell(name=name, config=cfg,
-                traffic=gen.load_mix(w["traffic"]),
+                traffic=gen.load_mix(w["traffic"], root / "bench" / "traffic"),
                 chips=int(w["chips"]),
-                limits=check.load_limits(name),
+                limits=check.load_limits(name, root / "bench" / "limits"),
                 end_to_end=[m for m in man["end_to_end"] if applies(m)],
                 per_layer=[m for m in man["per_layer"] if applies(m)])
 
@@ -110,23 +118,6 @@ def devices_for(chips: int, require_tpu: bool = True):
 
 
 # -- the program's state in the benchmark's weights --------------------------
-
-def _to_zoo(w: Dict) -> Dict:
-    """Canonical weights -> the program's parameter tree. The program's
-    RMSNorm multiplies by (1 + gamma), so its scales are the canonical
-    scales minus 1."""
-    L = w["layers"]
-    raw = {"embed": w["embed"], "final_norm": w["final_norm"] - 1,
-           "layers": {"ln1": L["ln1"] - 1, "ln2": L["ln2"] - 1,
-                      "attn": {"wq": L["wq"], "wk": L["wk"], "wv": L["wv"],
-                               "wo": L["wo"], "q_norm": L["q_norm"] - 1,
-                               "k_norm": L["k_norm"] - 1},
-                      "mlp": {"w_gate": L["w_gate"], "w_up": L["w_up"],
-                              "w_down": L["w_down"]}}}
-    if "unembed" in w:
-        raw["unembed"] = w["unembed"]
-    return raw
-
 
 def program_leaf_norms(tree: Dict, n_layers: int) -> Dict:
     """Leaf norms of a program-layout tree under the reference's names
@@ -175,7 +166,8 @@ class Run:
                              f"configuration's: {bad}")
         self.mesh = path.mesh if path is not None else make_mesh(
             cell.mesh_shape, ("data", "model"), devices=self.devices)
-        self.arch = steppath.arch_from_config(cfg)
+        program = cell.program
+        self.arch = program.arch(cfg)
         self.order, self.batches = gen.replay_set(
             cell.traffic, self.seed, int(cfg["vocab_size"]))
         self.corpora = [dict(enumerate(b)) for b in self.batches]
@@ -207,7 +199,7 @@ class Run:
         weights = jax.jit(lambda k: ref.make_weights(cfg, k))
 
         def params_of(w):
-            return prepare_params(self.arch, _to_zoo(w), self.mesh,
+            return prepare_params(self.arch, program.to_program(w), self.mesh,
                                   self.path.dtype, v_stages=1)
 
         def init(w):
@@ -353,6 +345,13 @@ class Run:
         stats = [d.memory_stats() or {} for d in self.devices]
         return int(max(s.get("peak_bytes_in_use", 0) for s in stats))
 
+    def scope_maps(self) -> Dict[str, Dict[str, str]]:
+        """``scopes.scope_map`` of every resident bucket's compiled step."""
+        maps = {}
+        for _, compiled in self.steps.values():
+            maps.update(scopes.scope_map(compiled))
+        return maps
+
     def free(self) -> None:
         self.params = self.opt = None
         self.steps = {}
@@ -372,14 +371,18 @@ class Run:
 
 
 def per_layer(cell: Cell, win: Dict, reduced: Optional[Dict],
-              peaks_by_bucket: Dict, bytes_limit: int, kind: str) -> Dict:
+              scoped: Optional[Dict], peaks_by_bucket: Dict,
+              bytes_limit: int, kind: str) -> Dict:
+    """The cell's per-layer metrics of a window (``bench/metrics``)."""
     from metrics import reader
     model_flops = sum(flops.step_flops(cell.config, s["docs"])
                       for s in win["steps"])
+    peak = peaks.peak(kind)
     run = {"steps": win["steps"], "peaks": peaks_by_bucket,
-           "bytes_limit": bytes_limit, "trace": reduced,
-           "flops": model_flops, "chips": cell.chips,
-           "peak_flops": peaks.peak(kind)["bf16_flops"]}
+           "bytes_limit": bytes_limit, "trace": reduced, "scopes": scoped,
+           "config": cell.config, "flops": model_flops, "chips": cell.chips,
+           "peak_flops": peak["bf16_flops"],
+           "peak_bytes_per_s": peak["hbm_bytes_per_s"]}
     out = {}
     for m in cell.per_layer:
         v = reader(m["name"])(run)
@@ -404,10 +407,13 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool,
     kind = run.devices[0].device_kind
     with tempfile.TemporaryDirectory() as tdir:
         win = run.window(seconds, trace_dir=tdir if trace else None)
-        reduced = None
+        reduced = scoped = None
         if trace:
-            reduced = devtrace.reduce(devtrace.load(tdir),
-                                      len(win["steps"]))
+            n = len(win["steps"])
+            maps = run.scope_maps()
+            recorded = scopes.load(tdir)
+            reduced = devtrace.reduce(recorded, n)
+            scoped = scopes.reduce(recorded, maps, n)
     mem = run.memory_peak()
     peaks_by_bucket = dict(run.path.peaks)
     limit = bytes_limit_of(run.devices)
@@ -419,7 +425,8 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool,
     device = {"platform": run.devices[0].platform, "kind": kind,
               "count": len(run.devices), "memory_peak_bytes": mem}
     if trace:
-        metrics = per_layer(cell, win, reduced, peaks_by_bucket, limit, kind)
+        metrics = per_layer(cell, win, reduced, scoped, peaks_by_bucket,
+                            limit, kind)
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
     else:

@@ -2,7 +2,9 @@
 backward and recomputation, averaged over the chips, in ms
 (``bench/scopes.py``; None where the trace holds no scopes)."""
 
+SCOPE = "mlp"
+
 
 def read(run):
     s = run.get("scopes")
-    return s["layers_ms"]["mlp"] if s and s["scoped"] else None
+    return s["layers_ms"][SCOPE] if s and s["scoped"] else None

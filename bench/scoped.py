@@ -6,8 +6,9 @@
 Set-up as ``bench/run.py`` (without the reference check), then an
 untraced window of ``--seconds`` and one traced pass over the replay set.
 Prints one JSON line: the step wall of both, the trace's busy and window
-time, the scope reduction (``bench/scopes.py``) and the readings of the
-metrics that read it, how the step executables were obtained (store
+time, the scope reduction (``bench/scopes.py``), the cell's per-layer
+metrics (``BENCHMARK.json``) of the traced pass as ``bench/run.py
+--trace 1`` reads them, how the step executables were obtained (store
 loads, compiles) and the ``repro.*`` host spans the trace holds. With
 ``--trace-out`` the compact trace and the scope maps are kept as JSON.
 """
@@ -28,9 +29,6 @@ sys.path.insert(0, str(Path(__file__).resolve().parent))
 import devtrace  # noqa: E402
 import harness  # noqa: E402
 import scopes  # noqa: E402
-from metrics import reader  # noqa: E402
-
-METRICS = ("ce_head_ms", "attention_ms", "mlp_ms", "recompute_share")
 
 
 def main() -> int:
@@ -49,9 +47,7 @@ def main() -> int:
     except harness.NoChip as e:
         log(f"FAIL: {e}")
         return 2
-    maps = {}
-    for _, compiled in run.steps.values():
-        maps.update(scopes.scope_map(compiled))
+    maps = run.scope_maps()
     plain = run.window(args.seconds)
     with tempfile.TemporaryDirectory() as tdir:
         traced = run.window(0, trace_dir=tdir)
@@ -70,7 +66,10 @@ def main() -> int:
         "steps": {"untraced": len(plain["steps"]), "traced": n},
         "busy_s": base["busy_s"], "window_s": base["window_s"],
         "scopes": red,
-        "metrics": {m: reader(m)({"scopes": red}) for m in METRICS},
+        "metrics": harness.per_layer(
+            run.cell, traced, base, red, dict(run.path.peaks),
+            harness.bytes_limit_of(run.devices),
+            run.devices[0].device_kind),
         "executables": {"store_loads": stats.warm_hits,
                         "compiles": stats.misses,
                         "ops_with_op_name": {k: len(v)

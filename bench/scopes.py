@@ -1,13 +1,15 @@
 """Device time by the program's named scopes.
 
-The step program names its layers with ``jax.named_scope``: ``embed``,
-``attention``, ``mlp``, ``ce_head``, ``optimizer``, ``handoff``,
-``sp_collective`` and, outermost, ``stage_tick``. The names reach the
-compiled module as each instruction's ``op_name`` metadata, e.g.
-``jit(f)/transpose(jvp(stage_tick))/.../rematted_computation/attention/
-dot_general``: the innermost of the eight names is the op's layer,
-``transpose(`` marks the backward and ``rematted_computation`` the
-recomputation. The profiler names device ops by instruction only, so
+The step program names its layers with ``jax.named_scope``. The scopes
+counted here (``SCOPES``) are those that a per-layer reader declares
+(``bench/metrics``: ``attention``, ``mlp``, ``ce_head``, ...) and the
+closing ones that no reader needs to name (``CLOSING``: ``embed``,
+``optimizer``, ``handoff``, ``sp_collective`` and, outermost,
+``stage_tick``). The names reach the compiled module as each
+instruction's ``op_name`` metadata, e.g. ``jit(f)/transpose(jvp(
+stage_tick))/.../rematted_computation/attention/dot_general``: the
+innermost of the counted names is the op's layer, ``transpose(`` marks
+the backward and ``rematted_computation`` the recomputation. The profiler names device ops by instruction only, so
 ``scope_map`` reads the compiled module's text and ``reduce`` joins the
 two.
 
@@ -25,9 +27,10 @@ import re
 from typing import Dict, Optional
 
 import devtrace
+import metrics
 
-SCOPES = ("embed", "attention", "mlp", "ce_head", "optimizer", "handoff",
-          "sp_collective", "stage_tick")
+CLOSING = ("embed", "optimizer", "handoff", "sp_collective", "stage_tick")
+SCOPES = tuple(dict.fromkeys(metrics.declared_scopes() + CLOSING))
 REMAT = "rematted_computation"
 MODULES_LINE = "XLA Modules"
 _MODULE = re.compile(r"^HloModule ([^\s,]+)")
@@ -107,9 +110,10 @@ def reduce(trace: Dict, maps: Dict[str, Dict[str, str]],
     window and looked up in the map of the module whose ``XLA Modules``
     interval holds its clipped start (outside every interval: the only
     map, if there is one); an op with no ``op_name`` there, or with none
-    of ``SCOPES`` in it, is ``unscoped``. Returns ``leaf_ms``, ``layers_ms`` (the eight scopes
-    and ``unscoped``, summing to ``leaf_ms``), ``backward_ms`` (the part
-    of each under ``transpose(``), ``remat_ms`` (under
+    of ``SCOPES`` in it, is ``unscoped``. Returns ``leaf_ms``,
+    ``layers_ms`` (each of ``SCOPES`` and ``unscoped``, summing to
+    ``leaf_ms``), ``backward_ms`` (the part of each under
+    ``transpose(``), ``remat_ms`` (under
     ``rematted_computation``), ``unmapped_ms`` (ops of a module with no
     map) and ``scoped`` (whether any op fell under a scope: a program
     built without scopes reads False).

@@ -20,26 +20,6 @@ from typing import Dict, List, Optional
 import numpy as np
 
 
-def arch_from_config(cfg: Dict):
-    """The program's ``ArchConfig`` for a Qwen3 configuration file."""
-    from repro.core.plan import ModelSpec
-    from repro.models.config import ArchConfig
-    if cfg["model_type"] != "qwen3":
-        raise ValueError(f"no program mapping for {cfg['model_type']!r}")
-    tied = bool(cfg["tie_word_embeddings"])
-    spec = ModelSpec(
-        name=f"qwen3-d{cfg['hidden_size']}-l{cfg['num_hidden_layers']}",
-        n_layers=int(cfg["num_hidden_layers"]),
-        d_model=int(cfg["hidden_size"]),
-        n_heads=int(cfg["num_attention_heads"]),
-        n_kv_heads=int(cfg["num_key_value_heads"]),
-        head_dim=int(cfg["head_dim"]), d_ff=int(cfg["intermediate_size"]),
-        vocab=int(cfg["vocab_size"]), qk_norm=True, tie_embeddings=tied)
-    return ArchConfig(spec=spec, rope_theta=float(cfg["rope_theta"]),
-                      rms_eps=float(cfg["rms_norm_eps"]),
-                      tie_embeddings=tied)
-
-
 def adamw_matches(hp: Dict) -> List[str]:
     """Where the program's AdamW defaults differ from the configuration."""
     from repro.optim import AdamWConfig

@@ -8,6 +8,7 @@ import json
 import numpy as np
 import pytest
 
+import configs
 import steppath
 from tiny import DATA
 
@@ -20,8 +21,8 @@ def test_step_path_matches_train(mesh_shape):
     from repro.launch.mesh import make_mesh
     from repro.launch.train import TrainLoopConfig, train
 
-    arch = steppath.arch_from_config(
-        json.loads((DATA / "tiny.json").read_text()))
+    cfg = json.loads((DATA / "tiny.json").read_text())
+    arch = configs.program(cfg).arch(cfg)
     mesh = make_mesh(mesh_shape, ("data", "model"))
     seed, batch, context = 3, 4, 128
     loop = TrainLoopConfig(steps=1, global_batch=batch, context=context,

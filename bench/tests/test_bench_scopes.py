@@ -16,6 +16,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+import configs
 import devtrace
 import scopes
 import steppath
@@ -27,8 +28,8 @@ BENCH = Path(__file__).resolve().parents[1]
 # -- the compiled step ------------------------------------------------------
 
 def _arch():
-    return steppath.arch_from_config(
-        json.loads((DATA / "tiny.json").read_text()))
+    cfg = json.loads((DATA / "tiny.json").read_text())
+    return configs.program(cfg).arch(cfg)
 
 
 def _compiled_step(mesh_shape=(1, 1), l_ckpt=1, **geom):
@@ -316,3 +317,69 @@ def test_scope_map_reads_the_module_text():
     with pytest.raises(ValueError):
         scopes.scope_map(_Text("not a module"))
     assert scopes.module_name("jit_step(1234)") == "jit_step"
+
+
+# -- scopes declared by readers ---------------------------------------------
+
+def test_scopes_are_the_readers_and_the_closing_ones():
+    import metrics
+    assert set(scopes.SCOPES) == set(metrics.declared_scopes()) | set(
+        scopes.CLOSING)
+    assert len(scopes.SCOPES) == len(set(scopes.SCOPES)) == 8
+    # attention, mlp and ce_head are counted because a reader reads them
+    assert {"attention", "mlp", "ce_head"} <= set(metrics.declared_scopes())
+    assert not {"attention", "mlp", "ce_head"} & set(scopes.CLOSING)
+
+
+def test_a_new_reader_declares_a_new_scope(tmp_path):
+    """A reader file that declares ``SCOPE = "moe"`` makes ``moe`` a
+    counted scope, with no edit: an op under it is no longer
+    ``unscoped``."""
+    import importlib
+
+    import metrics
+    (tmp_path / "moe_ms.py").write_text(
+        'SCOPE = "moe"\n\n\ndef read(run):\n'
+        '    return run["scopes"]["layers_ms"][SCOPE]\n')
+    path = list(metrics.__path__)
+    metrics.__path__.append(str(tmp_path))
+    importlib.invalidate_caches()
+    try:
+        assert "moe" in metrics.declared_scopes()
+        importlib.reload(scopes)
+        path_ = f"{STEP}/stage_tick/moe/dot_general"
+        assert scopes.layer_of(path_) == "moe"
+        maps = {"jit_step": dict(MAPS["jit_step"], **{"fusion.7": path_})}
+        r = scopes.reduce(HAND, maps, n_steps=2)
+        assert r["layers_ms"]["moe"] == pytest.approx(20e-6 / 4)
+        assert sum(r["layers_ms"].values()) == pytest.approx(r["leaf_ms"])
+        assert metrics.reader("moe_ms")({"scopes": r}) == pytest.approx(
+            20e-6 / 4)
+    finally:
+        metrics.__path__[:] = path
+        sys.modules.pop("metrics.moe_ms", None)
+        importlib.reload(scopes)
+    assert "moe" not in scopes.SCOPES
+
+
+def test_optimizer_and_head_roofline_read_the_reduction():
+    from metrics import reader
+    r = scopes.reduce(HAND, MAPS, n_steps=2)
+    cfg = {"hidden_size": 4, "vocab_size": 10}
+    steps = [{"docs": [4, 1]}, {"docs": [3]}]
+    run = {"scopes": r, "config": cfg, "steps": steps, "chips": 2,
+           "peak_flops": 1e9, "peak_bytes_per_s": 1e9}
+    assert reader("optimizer_ms")(run) == pytest.approx(20e-6 / 4)
+    # head: 6 x 4 x 10 x (3 + 2) targets = 1200 FLOP over 2 chips x 1e9;
+    # least bytes 3 x 2 x 40 = 240 a step, 2 steps, over 2 x 1e9: the
+    # FLOPs bound it. The ce_head scope: 50 ns over 2 chips and 2 steps
+    # -> 12.5 ns a step and chip, 25 ns a chip for the 2 steps.
+    assert reader("ce_head_roofline")(run) == pytest.approx(
+        100 * (1200 / 2e9) / 25e-9)
+    run["peak_bytes_per_s"] = 1e6     # the bytes bound it now
+    assert reader("ce_head_roofline")(run) == pytest.approx(
+        100 * (480 / 2e6) / 25e-9)
+    for name in ("optimizer_ms", "ce_head_roofline"):
+        assert reader(name)(dict(run, scopes=None)) is None
+    no_head = dict(r, layers_ms=dict(r["layers_ms"], ce_head=0.0))
+    assert reader("ce_head_roofline")(dict(run, scopes=no_head)) is None

@@ -8,6 +8,7 @@ from pathlib import Path
 import pytest
 
 import devtrace
+import scopes
 
 DATA = Path(__file__).resolve().parent / "data"
 
@@ -85,3 +86,59 @@ def test_recorded_v5e_trace():
     assert all(label in devtrace.HOST_SPANS + ("none",)
                for label, _ in r["idle_gaps"])
     assert r["device_ops"] == sorted(r["device_ops"], key=lambda x: -x[1])
+
+
+def _xspace(planes) -> str:
+    """Text proto of an XSpace: ``{plane: {line: [[name, start_ns,
+    dur_ns], ...]}}``, each event at ``offset_ps`` from a line at 0."""
+    out = []
+    for pid, (plane, lines) in enumerate(planes.items(), 1):
+        names = {}
+        body = []
+        for lid, (line, events) in enumerate(lines.items(), 1):
+            evs = " ".join(
+                f"events {{ metadata_id: "
+                f"{names.setdefault(n, len(names) + 1)} "
+                f"offset_ps: {int(s * 1000)} duration_ps: {int(d * 1000)} }}"
+                for n, s, d in events)
+            body.append(f'lines {{ id: {lid} name: "{line}" '
+                        f'timestamp_ns: 0 {evs} }}')
+        meta = " ".join(f'event_metadata {{ key: {i} value {{ id: {i} '
+                        f'name: "{n}" }} }}' for n, i in names.items())
+        out.append(f'planes {{ id: {pid} name: "{plane}" '
+                   f'{" ".join(body)} {meta} }}')
+    return "\n".join(out)
+
+
+def test_loaders_agree_on_the_recorded_trace(tmp_path):
+    """The recorded v5e trace written back as an ``.xplane.pb``, with an
+    ``XLA Modules`` line and the program's ``repro.*`` spans added:
+    ``scopes.load`` reads what ``devtrace.load`` reads and more, and
+    ``devtrace.reduce`` gives the same numbers from either, and from the
+    recorded trace itself."""
+    from jax.profiler import ProfileData
+    with gzip.open(DATA / "trace-v5e-1chip.json.gz", "rt") as f:
+        trace = json.load(f)
+    (w0, wd), = [(s, d) for n, s, d in trace["host"] if n == "window"]
+    modules = [["jit_step(7)", w0, wd / 2], ["jit_step(7)", w0 + wd / 2,
+                                             wd / 2]]
+    spans = [["repro.plan_batch", w0 + 10.0, 100.0],
+             ["repro.materialize", w0 + 200.0, 50.0]]
+    text = _xspace({
+        "/device:TPU:0": {devtrace.OPS_LINE: trace["devices"]["TPU:0"],
+                          scopes.MODULES_LINE: modules},
+        "/host:CPU": {"python": trace["host"] + spans
+                      + [["unrelated", w0, 5.0]]}})
+    run_dir = tmp_path / "plugins" / "profile" / "run"
+    run_dir.mkdir(parents=True)
+    (run_dir / "host.xplane.pb").write_bytes(
+        ProfileData.text_proto_to_serialized_xspace(text))
+    bare, full = devtrace.load(str(tmp_path)), scopes.load(str(tmp_path))
+    assert bare["devices"] == trace["devices"]
+    assert sorted(bare["host"]) == sorted(trace["host"])
+    assert full["devices"] == bare["devices"] and full["host"] == bare["host"]
+    assert full["modules"] == {"TPU:0": modules}
+    assert sorted(full["spans"]) == sorted(spans)
+    r = devtrace.reduce(full, n_steps=1)
+    assert r == devtrace.reduce(bare, n_steps=1)
+    assert r == pytest.approx(devtrace.reduce(trace, n_steps=1))
