@@ -4,12 +4,21 @@ A configuration file (``<name>.json``) holds the published sizes as they
 are run, and names two modules of this package:
 
 - ``reference``: the plain reference of the family, which imports nothing
-  of the program (``make_weights``, ``run_key``, ``pack``, ``train``);
+  of the program (``make_weights``, ``run_key``, ``pack``, ``train``, and
+  ``leaf_norms(weights)``, which names every leaf the comparison checks);
 - ``program``: the adapter that hands the family to the program, with
   ``arch(cfg)`` (the program's ``ArchConfig``), ``to_program(weights)``
-  (the reference's canonical weights as the program's parameter tree) and
+  (the reference's canonical weights as the program's parameter tree),
+  ``from_program(cfg, tree)`` (a tree of the program's parameter layout,
+  after the program has stacked and padded it, back in the reference's
+  layout: structure only, no value changed) and
   ``step_flops(cfg, doc_lengths)`` (model FLOPs of a step,
   ``bench/flops.py``).
+
+The comparison names the program's leaves with the reference's own
+``leaf_norms`` of ``from_program``'s tree, so a family whose layers differ
+(a leading dense layer, sub-blocks that hold leaves of one name) lays its
+trees out as it likes.
 
 A new family joins the benchmark with new files alone: its configuration,
 these two modules, a traffic mix and the cell's limits.
@@ -21,7 +30,7 @@ import importlib
 from types import ModuleType
 from typing import Dict
 
-PROGRAM_FUNCTIONS = ("arch", "to_program", "step_flops")
+PROGRAM_FUNCTIONS = ("arch", "to_program", "from_program", "step_flops")
 
 
 def program(cfg: Dict) -> ModuleType:

@@ -1,6 +1,6 @@
 """The program adapter of the Qwen3 family (``bench/configs/__init__.py``):
 its ``ArchConfig``, its parameter tree from the reference's canonical
-weights (``qwen3.make_weights``), and its model FLOPs."""
+weights (``qwen3.make_weights``) and back, and its model FLOPs."""
 
 from __future__ import annotations
 
@@ -42,6 +42,35 @@ def to_program(w: Dict) -> Dict:
     if "unembed" in w:
         raw["unembed"] = w["unembed"]
     return raw
+
+
+def from_program(cfg: Dict, tree: Dict) -> Dict:
+    """A tree of the program's parameter layout (parameters, AdamW's
+    moments, or a difference of two) in the canonical layout: the inverse
+    of ``to_program`` followed by ``prepare_params`` at ``v_stages=1``.
+    The stage-stacked layers ``[d_p, L_s, ...]`` become ``[L, ...]`` less
+    the layers that pad the last stage, and the embedding and head lose
+    the rows that pad the vocabulary. No value changes: a program scale is
+    the canonical one minus 1, which a difference cancels."""
+    n, v = int(cfg["num_hidden_layers"]), int(cfg["vocab_size"])
+    S = tree["stages"]
+
+    def layers(x):
+        return x.reshape(-1, *x.shape[2:])[:n]
+
+    A, M = S["attn"], S["mlp"]
+    out = {"embed": tree["embed"][:v], "final_norm": tree["final_norm"],
+           "layers": {"ln1": layers(S["ln1"]), "ln2": layers(S["ln2"]),
+                      "wq": layers(A["wq"]), "wk": layers(A["wk"]),
+                      "wv": layers(A["wv"]), "wo": layers(A["wo"]),
+                      "q_norm": layers(A["q_norm"]),
+                      "k_norm": layers(A["k_norm"]),
+                      "w_gate": layers(M["w_gate"]),
+                      "w_up": layers(M["w_up"]),
+                      "w_down": layers(M["w_down"])}}
+    if "unembed" in tree:
+        out["unembed"] = tree["unembed"][:v]
+    return out
 
 
 def matmul_params(cfg: Dict) -> int:

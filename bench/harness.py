@@ -16,6 +16,7 @@ from __future__ import annotations
 import gc
 import importlib
 import json
+import math
 import sys
 import time
 from dataclasses import dataclass, field
@@ -117,26 +118,37 @@ def devices_for(chips: int, require_tpu: bool = True):
     return devs[:chips]
 
 
-# -- the program's state in the benchmark's weights --------------------------
+# -- the program's state in the reference's layout ---------------------------
 
-def program_leaf_norms(tree: Dict, n_layers: int) -> Dict:
-    """Leaf norms of a program-layout tree under the reference's names
-    (stage-stacked layers [d_p, L_s, ...] -> ``layers.<i>.<name>``)."""
+def leaf_norms(cell: Cell) -> Callable[[Dict], Dict]:
+    """Leaf norms of a program-layout tree (parameters, AdamW's ``m``, or
+    a difference of two), under the reference's names: the family's
+    ``from_program`` maps the tree back to the reference's layout, and
+    the reference's own ``leaf_norms`` names its leaves."""
+    ref, program, cfg = cell.reference, cell.program, cell.config
+    return lambda tree: ref.leaf_norms(program.from_program(cfg, tree))
+
+
+def check_layout(cell: Cell, params) -> None:
+    """Raises where ``from_program`` of the program's (abstract) parameter
+    tree holds another number of elements than the tree ``to_program``
+    makes from the reference's weights: a leaf dropped or counted twice,
+    or padding left on. Shapes only."""
     import jax
-    import jax.numpy as jnp
-    out = {}
-    for name in ("embed", "unembed", "final_norm"):
-        if name in tree:
-            out[name] = jnp.sqrt(jnp.sum(jnp.square(
-                tree[name].astype(jnp.float32))))
-    for path, x in jax.tree_util.tree_flatten_with_path(tree["stages"])[0]:
-        leaf = jax.tree_util.keystr(path).split("'")[-2]
-        r = jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)),
-                             axis=tuple(range(2, x.ndim))))
-        r = r.reshape(-1)
-        for i in range(n_layers):
-            out[f"layers.{i}.{leaf}"] = r[i]
-    return out
+    ref, program, cfg = cell.reference, cell.program, cell.config
+
+    def size(tree):
+        return sum(math.prod(x.shape) for x in jax.tree.leaves(tree))
+
+    made = size(jax.eval_shape(
+        lambda k: program.to_program(ref.make_weights(cfg, k)),
+        ref.run_key(0)))
+    back = size(jax.eval_shape(lambda t: program.from_program(cfg, t),
+                               params))
+    if back != made:
+        raise ValueError(
+            f"from_program of {cfg['program']!r} gives back {back} "
+            f"elements; to_program's tree holds {made}")
 
 
 @dataclass
@@ -186,7 +198,9 @@ class Run:
         builder = next(iter(self.steps.values()))[0]
         if builder.geom.v_stages != 1:
             raise NotImplementedError("interleaved layer stacking")
-        pspecs, ospecs, _ = builder.specs(builder.abstract_params())
+        abstract = builder.abstract_params()
+        check_layout(cell, abstract)
+        pspecs, ospecs, _ = builder.specs(abstract)
         named = lambda t: jax.tree.map(  # noqa: E731
             lambda s: NamedSharding(self.mesh, s), t,
             is_leaf=lambda x: isinstance(x, P))
@@ -242,17 +256,26 @@ class Run:
         _, fn = self.steps[str(self.path.key(self.plans[b]))]
         self.params, self.opt, metrics = self._call(fn, self._feed(b))
         jax.block_until_ready((self.params, metrics["loss"]))
+        self._count(b, metrics)
+        return metrics
+
+    def _count(self, b: int, metrics: Dict):
+        """The step's counters, every metric but the loss, in one read
+        from the device (NumPy values), with its token count held against
+        the benchmark's: ``(benchmark's count, counters)``."""
+        import jax
+        counters = jax.device_get(
+            {k: v for k, v in metrics.items() if k != "loss"})
         real = gen.real_tokens(self.batches[b])
         self.readings["tokens_gap"] = max(
-            self.readings["tokens_gap"], abs(float(metrics["tokens"]) - real))
-        return metrics
+            self.readings["tokens_gap"], abs(float(counters["tokens"]) - real))
+        return real, counters
 
     def _read_grad(self) -> None:
         """The first gradient as AdamW received it: m_1 / (1 - beta1)."""
         import jax
         b1 = float(self.cell.config["training"]["beta1"])
-        n = int(self.cell.config["num_hidden_layers"])
-        norms = jax.jit(lambda m: program_leaf_norms(m, n))(self.opt["m"])
+        norms = jax.jit(leaf_norms(self.cell))(self.opt["m"])
         self.readings["grad"] = {k: float(v) / (1 - b1)
                                  for k, v in norms.items()}
 
@@ -261,11 +284,11 @@ class Run:
         of the parameters the step returned (its compute copies)."""
         import jax
         import jax.numpy as jnp
-        n = int(self.cell.config["num_hidden_layers"])
         p0 = self._params_of(key)
-        change = jax.jit(lambda a, b: program_leaf_norms(jax.tree.map(
+        norms = leaf_norms(self.cell)
+        change = jax.jit(lambda a, b: norms(jax.tree.map(
             lambda x, y: x.astype(jnp.float32) - y.astype(jnp.float32),
-            a, b), n))
+            a, b)))
         for name, now in (("delta", self.opt["master"]),
                           ("copy_delta", self.params)):
             self.readings[name] = {k: float(v)
@@ -321,14 +344,12 @@ class Run:
             with TraceAnnotation("block"):
                 jax.block_until_ready((self.params, metrics["loss"]))
             t_end = time.perf_counter()
-            real = gen.real_tokens(self.batches[b])
-            self.readings["tokens_gap"] = max(
-                self.readings["tokens_gap"],
-                abs(float(metrics["tokens"]) - real))
+            real, counters = self._count(b, metrics)
             k = self.path.key(plan)
             steps.append({"batch": b, "bucket": key, "real": real,
                           "slots": k.n_chunks * k.cap, "solve_ms": solve_ms,
-                          "docs": [len(d) for d in self.batches[b]]})
+                          "docs": [len(d) for d in self.batches[b]],
+                          "counters": counters})
             if last or (not trace_dir and t_end - t0 >= seconds):
                 break
         win.__exit__(None, None, None)

@@ -5,8 +5,12 @@ is the dict the harness builds from the traced window:
 
 - ``steps``: one dict per step of the window, with ``real`` (target-
   bearing tokens), ``docs`` (its documents' lengths), ``slots`` (chunks x
-  capacity of its bucket), ``bucket`` and ``solve_ms`` (the benchmark's
-  span around ``plan_batch``);
+  capacity of its bucket), ``bucket``, ``solve_ms`` (the benchmark's
+  span around ``plan_batch``) and ``counters``: every metric the
+  compiled step returns but its loss (today ``tokens``, ``grad_norm``,
+  ``lr``), read from the device once after the step, as NumPy values; a
+  family's step that counts more (routed pairs, say) hands it to its
+  readers here;
 - ``peaks`` (compiled peak bytes per bucket) and ``bytes_limit``;
 - ``trace``: the reduction of ``bench/devtrace.py``, or None;
 - ``scopes``: the reduction of ``bench/scopes.py`` (device time a step

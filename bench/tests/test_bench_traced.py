@@ -1,5 +1,6 @@
-"""A traced run hands the readers the scope reduction, and an untraced run
-reads no compiled module's text. At the tiny cell's size on the CPU: the
+"""A traced run hands the readers the scope reduction, an untraced run
+reads no compiled module's text, and every step of a window carries the
+step's own counters. At the tiny cell's size on the CPU: the
 CPU's profile holds the window's host spans but no TPU plane, so the
 device operations of the traced window are planted into what
 ``scopes.load`` read, one under each of four scopes, named by the
@@ -73,3 +74,14 @@ def test_untraced_run_reads_no_module_text(monkeypatch):
     out = tiny.run()
     assert out["correct"], out["checks"]
     assert set(out["metrics"]) == {"tokens_per_s", "setup_s"}
+
+
+def test_window_steps_carry_the_step_counters():
+    run = harness.Run(tiny.cell(), 7, log=lambda m: None)
+    run.prepare(require_tpu=False)
+    steps = run.window(0.5)["steps"]
+    assert steps
+    for s in steps:
+        assert "loss" not in s["counters"]
+        assert {"tokens", "grad_norm", "lr"} <= set(s["counters"])
+        assert s["counters"]["tokens"] == s["real"]
